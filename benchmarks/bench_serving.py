@@ -25,7 +25,7 @@ import numpy as np
 
 from repro import configs
 from repro.core import reweighted as RW
-from repro.launch.serve import SPARSE_SPEC
+from repro.launch.serve import sparse_spec
 from repro.models import transformer as T
 from repro.serve.compile import CompileSpec, compile_model
 from repro.serve.engine import ServingEngine
@@ -38,9 +38,9 @@ SEQ_CAP = 48
 def _packed_smoke_lm():
     cfg = configs.get(ARCH, smoke=True)
     params = T.init_lm(jax.random.PRNGKey(0), cfg)
-    masks = RW.magnitude_block_masks(params, SPARSE_SPEC, None, rate=0.6)
+    masks = RW.magnitude_block_masks(params, sparse_spec(cfg), None, rate=0.6)
     params = apply_masks(params, masks)
-    params, _ = compile_model(params, masks, SPARSE_SPEC,
+    params, _ = compile_model(params, masks, sparse_spec(cfg),
                               spec=CompileSpec(keep_dense=False))
     return params, cfg
 

@@ -49,13 +49,15 @@ kernels, which stay as the parity oracle.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 from repro.core import bcs as BCS
 
@@ -88,16 +90,95 @@ def _kernel(k_idx, x_ref, w_ref, s_ref, b_ref, o_ref, acc_ref, *, n_l, act):
         o_ref[...] = out.astype(o_ref.dtype)
 
 
-def _auto_interpret() -> bool:
-    """Run the kernel body in interpret mode unless we are on real TPU.
+# TPU vreg lane width: Mosaic needs the minor dim of every block to be a
+# multiple of it (or the whole array dim), and the second-minor a multiple
+# of 8 (f32) / 16 (bf16) — ``_m_tile`` keeps bm on that grid.
+LANE = 128
 
-    The ``PALLAS_INTERPRET`` env var overrides the auto-detection in both
-    directions ("1"/"true" forces the interpreter, "0"/"false" forces real
-    Mosaic lowering) so a TPU CI job can pin either mode explicitly."""
-    env = os.environ.get("PALLAS_INTERPRET", "").strip().lower()
-    if env:
-        return env not in ("0", "false", "no")
-    return jax.default_backend() != "tpu"
+# Kernels the TPU compiler refuses today, with Mosaic's reason (each one is
+# a strict xfail in tests/test_tpu_compile.py; ROADMAP S2).
+_REFUSED = {
+    "int8": "int8 bsr_matmul: its (1, 1) scale BlockSpec is neither "
+            "(8, 128)-aligned nor the full scale array",
+    "tap": "tap_gather_conv: its (bm, group) output block is not "
+           "lane-aligned at the packed group=1",
+    "conv_implicit": "bsr_conv2d_implicit: its in-kernel jnp.take is a "
+                     "1-D gather, and Mosaic lowers only 2-D gathers",
+    "tap_implicit": "tap_gather_conv_implicit: its (1, bm, group) output "
+                    "block is not lane-aligned at the packed group=1",
+}
+
+
+def tpu_refusal(kind, block=None, shape=None, value_dtype=None):
+    """Why the TPU compiler refuses ``kind``'s kernel, or None if it lowers.
+
+    ``kind`` is "bcs" (``bsr_matmul`` over a (bk, bn) ``block``-ed layout
+    of a (K, N) weight ``shape`` holding ``value_dtype`` values), "tap",
+    "conv_implicit" or "tap_implicit".  ``bsr_matmul`` tiles x as (bm, bk)
+    and the bias/output as (1 | bm, bn): Mosaic needs bk and bn to be
+    multiples of ``LANE`` or the whole K / N, and refuses the int8 path's
+    (1, 1) scale blocks."""
+    if kind != "bcs":
+        return _REFUSED[kind]
+    if value_dtype is not None and jnp.dtype(value_dtype) == jnp.int8:
+        return _REFUSED["int8"]
+    if block is None:
+        return None
+    bad = [f"{name}={b}" for name, b, full in (("bk", block[0], shape[0]),
+                                               ("bn", block[1], shape[1]))
+           if b % LANE and b != full]
+    if not bad:
+        return None
+    return (f"block {tuple(block)} of a {tuple(shape)} weight: "
+            f"{', '.join(bad)} is neither a multiple of the {LANE}-lane "
+            "tile nor the full dimension, so Mosaic cannot tile it")
+
+
+def refusal_here(kind, block=None, shape=None, value_dtype=None):
+    """``tpu_refusal`` on a TPU backend; None on any other, where the
+    interpreter runs every kernel."""
+    if jax.default_backend() != "tpu":
+        return None
+    return tpu_refusal(kind, block, shape, value_dtype)
+
+
+def _interpret_mode(interpret=None, refusal=None) -> bool:
+    """Resolve a kernel's ``interpret`` flag: ``None`` means interpret mode
+    off the TPU and Mosaic lowering on it — the backend alone decides.  A
+    ``refusal`` (``refusal_here``'s answer) raises instead: a kernel the
+    TPU compiler refuses never falls back to the interpreter there."""
+    if refusal:
+        raise NotImplementedError(
+            f"{refusal}, so it does not run on TPU (ROADMAP S2)")
+    return (jax.default_backend() != "tpu") if interpret is None \
+        else interpret
+
+
+_SHARD_AXIS = contextvars.ContextVar("shard_axis", default=None)
+
+
+@contextlib.contextmanager
+def traced_on(mesh, shard_axis):
+    """Trace the kernels for a program over ``mesh`` (the serving engine
+    does, for its ``Dist``): every launch is placed per device, and
+    column-sharded layouts split over the mesh axis ``shard_axis``."""
+    token = _SHARD_AXIS.set(shard_axis)
+    try:
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            yield
+    finally:
+        _SHARD_AXIS.reset(token)
+
+
+def _device_mesh():
+    """The multi-device abstract mesh of ``traced_on``, or None — also None
+    inside a ``shard_map`` body, whose axes are manual.  Mosaic kernels
+    cannot be partitioned automatically, so in a program over several
+    devices every launch is placed per device explicitly."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1 or mesh.manual_axes:
+        return None
+    return mesh
 
 
 def _same_pads(size, k, s):
@@ -147,14 +228,15 @@ def bsr_matmul(x, values, k_idx, bias=None, scales=None, *, bm=128,
     values (Nb, L, bk, bn); k_idx (Nb, L) int32.  ``scales`` rides along
     for int8 values (``core.quant``): fp32, (Nb, L) per-block or (Nb,)
     per-block-column, dequantized in-kernel before the fp32-accumulated
-    dot.  ``interpret=None`` auto-detects the backend (Pallas lowering on
-    TPU, interpreter elsewhere).  ``out_dtype`` defaults to x.dtype; pass
+    dot (int8 does not lower for TPU yet, see ``tpu_refusal``).
+    ``interpret=None`` follows the backend (Pallas lowering on TPU,
+    interpreter elsewhere).  ``out_dtype`` defaults to x.dtype; pass
     jnp.float32 to keep the fp32 accumulator precision on a bf16 input."""
-    if interpret is None:
-        interpret = _auto_interpret()
     M, K = x.shape
     Nb, L, bk, bn = values.shape
     N = Nb * bn
+    interpret = _interpret_mode(interpret, refusal_here(
+        "bcs", (bk, bn), (K, N), values.dtype))
     bm, Mp = _m_tile(M, bm, x.dtype)
     assert K % bk == 0, (K, bk)
     if Mp != M:
@@ -214,7 +296,9 @@ def bsr_matmul_packed(x, layout, bias=None, *, bm=128, act="none",
     fuse into each bin's epilogue (bias is gathered into layout column
     order first); the final column gather restores the original output
     order.  Per-column accumulation order is identical to the single-bin
-    kernel, so reordered and unreordered results are bit-identical.
+    kernel (padding blocks add exact zeros at the END of each column's
+    sequential grid accumulation), so reordered and unreordered results
+    are bit-identical.
     Quantized layouts (int8 values, ``core.quant``) thread each bin's
     ``scales`` leaf into the launch for in-kernel dequantization.
 
@@ -224,6 +308,13 @@ def bsr_matmul_packed(x, layout, bias=None, *, bm=128, act="none",
     if layout.n_shards:
         return bsr_matmul_sharded(x, layout, bias=bias, bm=bm, act=act,
                                   interpret=interpret, out_dtype=out_dtype)
+    if _device_mesh() is not None:
+        # a replicated layout in a multi-device program: every device runs
+        # the whole launch on the replicated operands
+        call = functools.partial(bsr_matmul_packed, bm=bm, act=act,
+                                 interpret=interpret, out_dtype=out_dtype)
+        return jax.shard_map(call, in_specs=P(), out_specs=P(),
+                             check_vma=False)(x, layout, bias)
     outs = []
     for vals_b, kidx_b, sc_b, bias_b in zip(layout.values, layout.k_idx,
                                             layout.bin_scales(),
@@ -236,16 +327,19 @@ def bsr_matmul_packed(x, layout, bias=None, *, bm=128, act="none",
 
 
 def _sharded_launch(x, layout, bias, launch):
-    """Shared shard-parallel driver: ``jax.vmap`` of the per-bin ``launch``
-    over the leading shard axis of every per-bin leaf (values/indices/
-    scales/bias), then ``layout.merge_shards`` — one gather that is both
-    the cross-shard concat and the column un-reorder.  ``x`` is closed
-    over (replicated to every shard).  When the leaves carry a
-    ``NamedSharding`` over the mesh "model" axis, GSPMD partitions the
-    vmapped launches into per-device kernels and turns the merge into the
-    all-gather epilogue; on one device it is a plain batched launch —
-    numerics are identical either way (per-column accumulation order is
-    untouched, so sharded results are bit-identical to unsharded)."""
+    """Shared shard-parallel dispatch: the per-bin ``launch`` over each shard
+    of every per-bin leaf (values/indices/scales/bias, shard axis leading),
+    then ``layout.merge_shards`` — one gather that is both the cross-shard
+    concat and the column un-reorder.  ``x`` is replicated to every shard.
+
+    Under a multi-device mesh (``traced_on``) the shards map onto its
+    shard axis through ``shard_map``, one shard's launches per device, and GSPMD turns the
+    merge into the all-gather epilogue.  Without one, ``jax.vmap`` runs the
+    shards as a batched launch (interpret mode places it by GSPMD on the
+    CPU; Mosaic kernels cannot be partitioned that way).  BCS layouts stay
+    bit-identical to unsharded (per-column accumulation order is
+    untouched); tap layouts agree to fp32 rounding only, see
+    ``tap_gather_conv_packed``."""
     operands = {"values": layout.values, "idx": layout.shard_index_leaves()}
     if layout.scales is not None:
         operands["scales"] = layout.scales
@@ -253,16 +347,34 @@ def _sharded_launch(x, layout, bias, launch):
         operands["bias"] = layout.bin_bias(bias)
     n_bins = layout.n_bins
 
-    def shard_fn(op):
+    def shard_fn(xx, op):
         outs = []
         for b in range(n_bins):
             outs.append(launch(
-                x, op["values"][b], op["idx"][b],
+                xx, op["values"][b], op["idx"][b],
                 op["bias"][b] if "bias" in op else None,
                 op["scales"][b] if "scales" in op else None))
         return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=-1)
 
-    return layout.merge_shards(jax.vmap(shard_fn)(operands))
+    mesh = _device_mesh()
+    if mesh is None:
+        y = jax.vmap(lambda op: shard_fn(x, op))(operands)
+    else:
+        axis = _SHARD_AXIS.get()
+        if axis is None or mesh.shape.get(axis) != layout.n_shards:
+            raise ValueError(
+                f"a layout with {layout.n_shards} column shards needs a "
+                f"shard axis of that size, got {axis!r} on "
+                f"{dict(mesh.shape)}")
+
+        def one_device(xx, op):
+            op = jax.tree_util.tree_map(lambda a: a[0], op)
+            return shard_fn(xx, op)[None]
+
+        y = jax.shard_map(one_device, in_specs=(P(), P(axis)),
+                          out_specs=P(axis), check_vma=False)(
+                              x, operands)
+    return layout.merge_shards(y)
 
 
 def bsr_matmul_sharded(x, layout, bias=None, *, bm=128, act="none",
@@ -331,10 +443,10 @@ def tap_gather_conv(x, values, t_idx, bias=None, scales=None, *, bm=128,
 
     The in-kernel gather runs on the VPU (per-filter tap sets defeat MXU
     tiling — the §5.2.4-style trade-off ``core.latency_model`` now prices);
-    like ``bsr_matmul``, ``interpret=None`` auto-detects the backend and
-    ragged M is padded here, never silently densified."""
-    if interpret is None:
-        interpret = _auto_interpret()
+    like ``bsr_matmul``, ``interpret=None`` follows the backend and
+    ragged M is padded here, never silently densified.  Mosaic refuses
+    this kernel today (``tpu_refusal``), so it raises on a TPU."""
+    interpret = _interpret_mode(interpret, refusal_here("tap"))
     M, R = x.shape
     G, L, gp = values.shape
     bm, Mp = _m_tile(M, bm, x.dtype)
@@ -392,7 +504,15 @@ def tap_gather_conv_packed(x, layout, bias=None, *, bm=128, act="none",
     back through ``inv_perm`` — the TapLayout mirror of
     ``bsr_matmul_packed``, including the quantized-scales plumbing and the
     tensor-parallel dispatch (``layout.n_shards > 0`` routes to
-    ``tap_gather_conv_sharded``)."""
+    ``tap_gather_conv_sharded``).
+
+    Unlike the BCS kernel, each filter's taps contract in ONE dot of
+    length ``L_b`` (its bin's padded tap degree).  Two tap layouts of the
+    same weight (other bin counts, reordered or not, sharded or not) give
+    bit-identical outputs only where every filter keeps the same ``L_b``;
+    otherwise the dot's reduction length changes, and with it XLA's
+    summation order, so results agree to fp32 rounding (a few ulp), not
+    bitwise."""
     if layout.n_shards:
         return tap_gather_conv_sharded(x, layout, bias=bias, bm=bm, act=act,
                                        interpret=interpret,
@@ -488,9 +608,8 @@ def _conv_implicit_bin(xp, values, taps, bias=None, scales=None, *, geom,
     the x BlockSpec pins the whole current image in VMEM (index depends on
     b only, so it is fetched once per image, not per block step) and each
     step gathers its (bm, bk) tile in-kernel — no patch tensor, no HBM
-    re-read per block."""
-    if interpret is None:
-        interpret = _auto_interpret()
+    re-read per block.  Refused by Mosaic today (``tpu_refusal``)."""
+    interpret = _interpret_mode(interpret, refusal_here("conv_implicit"))
     Hp, Wp, Ho, Wo, _ = geom
     B, _, C = xp.shape
     Nb, L, bk, bn = values.shape
@@ -615,9 +734,9 @@ def _tap_implicit_bin(xp, values, taps, bias=None, scales=None, *, geom,
     """One degree bin of the implicit tap-gather conv: xp (B, Hp*Wp, C),
     values (G, L, group), taps (G, L, 2) int32 per-slot (dy*Wp + dx, c)
     offsets.  Grid (B, M/bm, G), no cross-step accumulator — epilogue fused
-    into the single step, exactly like ``tap_gather_conv``."""
-    if interpret is None:
-        interpret = _auto_interpret()
+    into the single step, exactly like ``tap_gather_conv``.  Refused by
+    Mosaic today (``tpu_refusal``)."""
+    interpret = _interpret_mode(interpret, refusal_here("tap_implicit"))
     Hp, Wp, Ho, Wo, _ = geom
     B, _, C = xp.shape
     G, L, gp = values.shape

@@ -74,7 +74,8 @@ from repro.core import bcs as BCS
 from repro.core import quant as QUANT
 from repro.core.packed import PackedLayout
 from repro.kernels.bsr_matmul import (bsr_conv2d_implicit, bsr_matmul_packed,
-                                      conv_geometry, tap_gather_conv_implicit,
+                                      conv_geometry, refusal_here,
+                                      tap_gather_conv_implicit,
                                       tap_gather_conv_packed)
 from repro.kernels import ref
 
@@ -249,7 +250,10 @@ def pack_taps(w, mask, *, group=1, reorder=True, n_bins=8,
     (``core.quant``); prefer ``scale_granularity="out"`` for group=1
     layouts, where a per-slot scale would cost 4 bytes per stored value.
     ``n_shards > 0`` emits the tensor-parallel TapLayout (degree-balanced
-    filter-group shards; implies ``reorder``)."""
+    filter-group shards; implies ``reorder``).  Layouts of the same weight
+    with other knobs compute the same conv, bit-identically only where
+    each filter keeps its padded tap degree (see
+    ``bsr_matmul.tap_gather_conv_packed``); otherwise to fp32 rounding."""
     w = np.asarray(w)
     mask = np.asarray(mask)
     qspec = _quant_spec(value_dtype, scale_granularity)
@@ -338,10 +342,15 @@ def _pick_implicit(implicit, x, kh, kw, stride, padding, bk=None):
     image block the kernel pins in VMEM stays under
     ``_IMPLICIT_MAX_IMAGE_BYTES``.  The BCS path additionally needs its
     packing block inside one tap (bk | Cin); an explicit
-    ``implicit=True`` asserts that instead of silently falling back."""
+    ``implicit=True`` asserts that instead of silently falling back.
+    Auto never picks an implicit kernel the backend refuses
+    (``bsr_matmul.refusal_here``: both, on a TPU today)."""
     B, H, W, C = x.shape
     if implicit is None:
         if bk is not None and C % bk:
+            return False
+        if refusal_here("conv_implicit" if bk is not None
+                        else "tap_implicit"):
             return False
         ph, pw, _, _ = conv_geometry(H, W, kh, kw, stride, padding)
         image_bytes = ((H + ph[0] + ph[1]) * (W + pw[0] + pw[1]) * C

@@ -26,18 +26,51 @@ import numpy as np
 from repro import configs
 from repro.core import reweighted as RW
 from repro.data.pipeline import synthetic_batch
+from repro.kernels import bsr_matmul as BM
 from repro.kernels.ops import pack_cache_stats
 from repro.models import transformer as T
 from repro.serve.compile import CompileSpec, compile_model, compiled_summary
 from repro.serve.engine import ServingEngine, generate
 from repro.train.trainer import apply_masks
 
-SPARSE_SPEC = [(r"(attn/w[qkvo]|(ffn|moe)/(gate|up|down))/w",
-                RW.SchemeChoice("block", (16, 16))),
-               # SSM in/out projections pack too (PR 3); the narrower (16, 8)
-               # block tiles the smoke mamba2 in_proj (proj dim 296 = 37*8)
-               (r"ssm/(in_proj|out_proj)/w",
-                RW.SchemeChoice("block", (16, 8)))]
+PROJ_RE = r"(attn/w[qkvo]|(ffn|moe)/(gate|up|down))/w"
+SSM_RE = r"ssm/(in_proj|out_proj)/w"
+
+
+def sparse_spec(cfg):
+    """The served block-pruning spec for ``cfg``: attention, FFN and MoE
+    projections, plus the SSM in/out projections.
+
+    At published widths (d_model a multiple of 128) every block is the
+    (128, 128) lane tile, the block the Pallas kernel lowers for TPU (see
+    ``kernels.bsr_matmul.tpu_refusal``).  Smoke widths are narrower
+    than one tile, so they keep (16, 16) blocks, and (16, 8) for the SSM
+    projections (the smoke mamba2 in_proj is 296 = 37*8 wide); those run
+    in interpret mode only, and ``compile_model`` refuses them on TPU."""
+    if cfg.d_model % BM.LANE == 0:
+        fc = ssm = (BM.LANE, BM.LANE)
+    else:
+        fc, ssm = (16, 16), (16, 8)
+    return [(PROJ_RE, RW.SchemeChoice("block", fc)),
+            (SSM_RE, RW.SchemeChoice("block", ssm))]
+
+
+def init_params(cfg, seed=0):
+    """Random ``init_lm`` params for ``cfg``, initialized by one compiled
+    program: run op by op, a model at published widths spends over a
+    minute dispatching on a TPU."""
+    return jax.jit(T.init_lm, static_argnums=1)(jax.random.PRNGKey(seed),
+                                                cfg)
+
+
+def prune(params, cfg, rate):
+    """One-shot magnitude block pruning under ``sparse_spec(cfg)``:
+    (masked params, masks, spec).  The masks come from one compiled
+    program, for the reason ``init_params`` gives."""
+    spec = sparse_spec(cfg)
+    masks = jax.jit(lambda p: RW.magnitude_block_masks(
+        p, spec, None, rate=rate))(params)
+    return apply_masks(params, masks), masks, spec
 
 
 def main(argv=None):
@@ -77,17 +110,15 @@ def main(argv=None):
         # surface the store's structured warm-start / fallback reasons
         logging.basicConfig(level=logging.INFO)
     cfg = configs.get(args.arch, smoke=args.smoke)
-    params = T.init_lm(jax.random.PRNGKey(0), cfg)
+    params = init_params(cfg)
     b = synthetic_batch(0, 0, args.batch, args.prompt_len, cfg.vocab,
                         frontend_tokens=cfg.n_frontend_tokens
                         if cfg.family in ("encdec", "vlm") else 0,
                         d_model=cfg.d_model)
     if args.sparse:
-        masks = RW.magnitude_block_masks(params, SPARSE_SPEC, None,
-                                         rate=args.prune_rate)
-        params = apply_masks(params, masks)
+        params, masks, spec = prune(params, cfg, args.prune_rate)
         t0 = time.time()
-        params, report = compile_model(params, masks, SPARSE_SPEC,
+        params, report = compile_model(params, masks, spec,
                                        spec=CompileSpec(keep_dense=False),
                                        artifact_dir=args.artifacts)
         dt_compile = time.time() - t0
@@ -149,4 +180,6 @@ def _run_engine(params, cfg, args, mode):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -63,6 +63,7 @@ from repro.core import bcs as BCS
 from repro.core import quant as QUANT
 from repro.core import reweighted as RW
 from repro.core.packed import PackedLayout
+from repro.kernels import bsr_matmul as BM
 from repro.kernels import ops
 
 # schemes the sparse executors can exploit: FC block schemes pack the
@@ -481,6 +482,11 @@ def compile_model(params, masks=None, mapping=(), spec=None, *,
                result is then published crash-safely (tmp + atomic
                rename) for the next start.
 
+    On a TPU backend, a layer whose layout the Pallas kernels cannot lower
+    there (a block that is not lane-aligned, int8 values, tap layouts —
+    see ``kernels.bsr_matmul.tpu_refusal``) raises ``ValueError`` here,
+    before anything is packed: it is never packed and then interpreted.
+
     Every packed ``LayerReport`` carries the effective density, the
     pre-reorder padded column degree L, the post-reorder ``L_reordered``
     with its gain, the skipped-FLOP fraction, and the served value dtype;
@@ -503,6 +509,12 @@ def compile_model(params, masks=None, mapping=(), spec=None, *,
     gemm_bins = 4 if spec.n_bins is None else spec.n_bins
     tap_bins = 8 if spec.n_bins is None else spec.n_bins
     reorder = spec.reorder
+
+    def refuse_on_tpu(wpath, kind, *layout):
+        """On a TPU backend, raise if its kernels cannot serve this layer."""
+        why = BM.refusal_here(kind, *layout)
+        if why:
+            raise ValueError(f"{wpath} cannot be served on TPU: {why}")
 
     def walk(p, m, path):
         if not isinstance(p, dict):
@@ -556,6 +568,7 @@ def compile_model(params, masks=None, mapping=(), spec=None, *,
             # silently falling back to masked-dense.  Quantized taps always
             # use per-filter ("out") scales — group=1 slots hold single
             # values, so per-slot scales would cost 4 bytes per value.
+            refuse_on_tpu(wpath, "tap")
             if shards and w.shape[0] % shards:
                 shards = 0                      # tp does not divide filters
             tap = ops.pack_taps(w, mask, reorder=reorder, n_bins=tap_bins,
@@ -586,6 +599,7 @@ def compile_model(params, masks=None, mapping=(), spec=None, *,
             if gemm_block is None:
                 return skip(why)
             P, Q, Kh, Kw = w.shape
+            refuse_on_tpu(wpath, "bcs", gemm_block, (Kh * Kw * Q, P), vdt)
             wl = BCS.conv_lower(w)
             ml = BCS.conv_lower(np.broadcast_to(np.asarray(mask), w.shape))
             if shards and (wl.shape[-1] // gemm_block[1]) % shards:
@@ -604,6 +618,7 @@ def compile_model(params, masks=None, mapping=(), spec=None, *,
             K, N = w.shape[-2:]
             if K % block[0] or N % block[1]:
                 return skip(f"block {block} does not divide ({K}, {N})")
+            refuse_on_tpu(wpath, "bcs", block, (K, N), vdt)
             if shards and (N // block[1]) % shards:
                 shards = 0                  # tp does not divide Nb
             packed, stats = _pack_stacked(

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
+from repro.kernels import bsr_matmul as BM
 from repro.models import layers as L
 from repro.models import attention as A
 from repro.models import ssm as S
@@ -144,20 +145,34 @@ _JIT_CACHE: OrderedDict = OrderedDict()
 _JIT_CACHE_MAX = 32
 
 
-def _cached_jit(key, make):
+def _cached_jit(key, make, dist=None):
+    """``jax.jit(make())``, cached under ``key``.  With a ``dist`` the
+    function traces under its mesh, so the Pallas kernels place their
+    launches per device (``kernels.bsr_matmul.traced_on``)."""
     if key in _JIT_CACHE:
         _JIT_CACHE.move_to_end(key)
     else:
-        _JIT_CACHE[key] = jax.jit(make())
+        fn = make()
+        if dist is not None:
+            fn = _under_mesh(fn, dist)
+        _JIT_CACHE[key] = jax.jit(fn)
         while len(_JIT_CACHE) > _JIT_CACHE_MAX:
             _JIT_CACHE.popitem(last=False)
     return _JIT_CACHE[key]
 
 
+def _under_mesh(fn, dist):
+    def traced(*args):
+        with BM.traced_on(dist.mesh, dist.model_axis):
+            return fn(*args)
+    return traced
+
+
 def _jit_prefill(cfg, dist):
     return _cached_jit(
         ("prefill", cfg, id(dist)),
-        lambda: lambda p, t, f: prefill(p, cfg, t, frontend=f, dist=dist))
+        lambda: lambda p, t, f: prefill(p, cfg, t, frontend=f, dist=dist),
+        dist)
 
 
 def _jit_decode_loop(cfg, n_new, temperature, dist):
@@ -165,14 +180,14 @@ def _jit_decode_loop(cfg, n_new, temperature, dist):
         ("loop", cfg, n_new, temperature, id(dist)),
         lambda: lambda p, t, c, s, k: T.decode_loop(
             p, cfg, t, c, s, n_new, temperature=temperature, key=k,
-            dist=dist))
+            dist=dist), dist)
 
 
 def _jit_decode_step(cfg, dist):
     return _cached_jit(
         ("step", cfg, id(dist)),
         lambda: lambda p, tok, c, pos: T.decode_step(p, cfg, tok, c, pos,
-                                                     dist=dist))
+                                                     dist=dist), dist)
 
 
 def generate(params, cfg: ArchConfig, tokens, n_new, frontend=None,
@@ -211,7 +226,7 @@ def _jit_serving_step(cfg, dist):
                          axis=-1)
             return nxt, ok, cache
         return step
-    return _cached_jit(("serving_step", cfg, id(dist)), make)
+    return _cached_jit(("serving_step", cfg, id(dist)), make, dist)
 
 
 class ServingEngine:

@@ -18,7 +18,7 @@ from repro import configs
 from repro.core import reweighted as RW
 from repro.core import validate as V
 from repro.core.packed import DegradedLayer
-from repro.launch.serve import SPARSE_SPEC
+from repro.launch.serve import sparse_spec
 from repro.models import transformer as T
 from repro.serve import artifacts as ART
 from repro.serve import engine as E
@@ -59,9 +59,9 @@ def packed_lm():
     """Masked + compiled smoke model (keep_dense=True so every packed
     layer carries the masked-dense fallback the degrade path needs)."""
     params, cfg = _lm("yi-9b")
-    masks = RW.magnitude_block_masks(params, SPARSE_SPEC, None, rate=0.6)
+    masks = RW.magnitude_block_masks(params, sparse_spec(cfg), None, rate=0.6)
     params = apply_masks(params, masks)
-    exec_params, report = compile_model(params, masks, SPARSE_SPEC,
+    exec_params, report = compile_model(params, masks, sparse_spec(cfg),
                                         spec=CompileSpec(keep_dense=True))
     return cfg, params, masks, exec_params, report
 
@@ -337,9 +337,9 @@ def test_crashed_publish_staging_husk_is_ignored(tmp_path, packed_lm):
     atomic-rename protocol means the published artifact stays warm."""
     cfg, params, masks, _, _ = packed_lm
     spec = CompileSpec(keep_dense=True)
-    compile_model(params, masks, SPARSE_SPEC, spec=spec,
+    compile_model(params, masks, sparse_spec(cfg), spec=spec,
                   artifact_dir=tmp_path)        # cold pack + publish
-    key = ART.model_digest(params, masks, SPARSE_SPEC, spec=spec)
+    key = ART.model_digest(params, masks, sparse_spec(cfg), spec=spec)
     rec = F.crash_publish(tmp_path, key, stage="staging")
     assert rec.kind == "crashed_publish"
     warm = ART.load_grafted(tmp_path, key, params, keep_dense=True)
@@ -352,10 +352,10 @@ def test_crashed_publish_torn_artifact_repacks(tmp_path, packed_lm):
     oracle-exact."""
     cfg, params, masks, exec_params, _ = packed_lm
     spec = CompileSpec(keep_dense=True)
-    key = ART.model_digest(params, masks, SPARSE_SPEC, spec=spec)
+    key = ART.model_digest(params, masks, sparse_spec(cfg), spec=spec)
     F.crash_publish(tmp_path, key, stage="torn")
     assert ART.load_grafted(tmp_path, key, params, keep_dense=True) is None
-    repacked, report = compile_model(params, masks, SPARSE_SPEC, spec=spec,
+    repacked, report = compile_model(params, masks, sparse_spec(cfg), spec=spec,
                                      artifact_dir=tmp_path)
     assert any(r.packed for r in report)
     prompts = _prompts(cfg, [8, 5], seed=9)

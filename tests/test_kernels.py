@@ -108,15 +108,50 @@ def test_ops_pack_and_run():
 
 
 def test_pallas_interpret_env_override(monkeypatch):
-    """PALLAS_INTERPRET pins the kernel execution mode in both directions
-    (the TPU CI hook); unset falls back to backend auto-detection."""
+    """Interpret mode follows ``jax.default_backend()`` alone: no
+    environment variable can put the kernels in interpret mode on a TPU,
+    or force Mosaic lowering elsewhere."""
     from repro.kernels import bsr_matmul as BM
 
-    monkeypatch.setenv("PALLAS_INTERPRET", "1")
-    assert BM._auto_interpret() is True
-    monkeypatch.setenv("PALLAS_INTERPRET", "false")
-    assert BM._auto_interpret() is False
-    monkeypatch.setenv("PALLAS_INTERPRET", "")
-    assert BM._auto_interpret() == (jax.default_backend() != "tpu")
-    monkeypatch.delenv("PALLAS_INTERPRET")
-    assert BM._auto_interpret() == (jax.default_backend() != "tpu")
+    for env in ("1", "false", ""):
+        monkeypatch.setenv("PALLAS_INTERPRET", env)
+        for backend, want in (("tpu", False), ("cpu", True), ("gpu", True)):
+            monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+            assert BM._interpret_mode() is want
+            assert BM._interpret_mode(interpret=not want) is (not want)
+    monkeypatch.undo()
+    assert BM._interpret_mode() == (jax.default_backend() != "tpu")
+
+
+def test_refused_kernels_raise_on_tpu(monkeypatch):
+    """A kernel the TPU compiler refuses raises on a TPU backend, whatever
+    ``interpret`` asks for: it never falls back to the interpreter."""
+    from repro.kernels import bsr_matmul as BM
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert BM._interpret_mode(interpret=True) is True   # served kernel
+    assert BM.refusal_here("bcs", (128, 128), (4096, 4096)) is None
+    for kind in ("tap", "conv_implicit", "tap_implicit"):
+        for interpret in (None, True):
+            with pytest.raises(NotImplementedError, match="ROADMAP S2"):
+                BM._interpret_mode(interpret, BM.refusal_here(kind))
+    x, vals, kidx, _, _ = make_case(16, 128, 128, 128, 128, zero_frac=0.0)
+    scales = jnp.ones(kidx.shape, jnp.float32)
+    with pytest.raises(NotImplementedError, match="int8"):
+        bsr_matmul(x, vals.astype(jnp.int8), kidx, scales=scales)
+
+
+@pytest.mark.parametrize("block,shape,bad", [
+    ((128, 128), (4096, 11008), None),
+    ((128, 128), (11008, 4096), None),
+    ((16, 16), (64, 128), "bk=16, bn=16"),
+    ((128, 16), (4096, 512), "bn=16"),
+    ((64, 16), (64, 16), None),          # whole-array blocks tile
+])
+def test_tpu_block_refusal(block, shape, bad):
+    from repro.kernels import bsr_matmul as BM
+
+    why = BM.tpu_refusal("bcs", block, shape, jnp.bfloat16)
+    assert why is None if bad is None else bad in why
+    assert "int8" in BM.tpu_refusal("bcs", block, shape, jnp.int8)
+    assert BM.refusal_here("bcs", block, shape) is None   # not a TPU

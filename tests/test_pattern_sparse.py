@@ -1,7 +1,7 @@
 """Pattern/connectivity CONV layers through the tap-gather path:
 ``pattern_lower`` round-trips, packed-vs-masked-dense parity on both tiny
 conv archs (incl. connectivity pruning and the 5x5 kernel), reorder
-bit-identity through ``sparse_conv2d_pattern``, the compile_model routing
+parity through ``sparse_conv2d_pattern``, the compile_model routing
 (a pattern pick compiles to a sparse producer, never the logged dense
 fallback), and the mapper -> compile regression."""
 import jax
@@ -21,6 +21,12 @@ from repro.train.trainer import apply_masks
 
 PATTERN_SPEC = [(r"(^|/)(c|pw|dw)\d+/w",
                  RW.SchemeChoice("pattern", connectivity=0.5))]
+
+# Two tap layouts of one weight that pad a filter to different tap degrees
+# contract it in dots of different length, and XLA may then sum in another
+# order: the same conv to fp32 rounding (a few ulp of outputs of order 1),
+# not bitwise.  Observed differences stay below 4e-6.
+TAP_RTOL = 1e-5
 
 
 def pattern_case(P, Q, kh=3, kw=3, connectivity=0.0, seed=0):
@@ -104,9 +110,13 @@ def test_sparse_conv2d_pattern_matches_dense_conv(P, Q, kh, kw, stride,
 
 @pytest.mark.parametrize("n_bins", [1, 2, 4])
 def test_sparse_conv2d_pattern_reorder_bit_identity(n_bins):
-    """Degree-binned tap layouts produce bit-identical outputs to the
-    unreordered layout — the epilogue gather relabels filters, each
-    filter's tap accumulation order is untouched."""
+    """Degree-binned tap layouts compute the same conv as the unreordered
+    layout — the epilogue gather relabels filters.  Bitwise equality holds
+    only while each filter keeps its padded tap degree: binning pads a
+    filter to its bin's max instead of the global max, so its single dot
+    has another reduction length and XLA may sum in another order, so the
+    outputs agree to fp32 rounding (``TAP_RTOL``).  One bin pads every
+    filter to the global max, as the unreordered layout does: bitwise."""
     wm, mask = pattern_case(64, 32, connectivity=0.5, seed=3)
     plain = ops.pack_taps(wm, mask, reorder=False)
     reord = ops.pack_taps(wm, mask, reorder=True, n_bins=n_bins)
@@ -116,7 +126,11 @@ def test_sparse_conv2d_pattern_reorder_bit_identity(n_bins):
                                    act="relu")
     y1 = ops.sparse_conv2d_pattern(x, reord, kh=3, kw=3, stride=2, bias=b,
                                    act="relu")
-    np.testing.assert_array_equal(np.asarray(y0), np.asarray(y1))
+    if n_bins == 1:
+        np.testing.assert_array_equal(np.asarray(y0), np.asarray(y1))
+    else:
+        np.testing.assert_allclose(np.asarray(y0), np.asarray(y1),
+                                   rtol=TAP_RTOL, atol=TAP_RTOL)
     assert reord.L_effective <= plain.L_max
 
 
@@ -170,11 +184,14 @@ def test_pack_taps_default_bins_shrink_connectivity_padding():
     b8 = ops.pack_taps(wm, mask)                  # default
     assert b8.n_bins == 8
     assert b8.padding_overhead < b4.padding_overhead
-    # bit-identical outputs regardless of binning
+    # the same conv regardless of binning: 4 and 8 bins pad filters to
+    # different tap degrees, so each filter's dot has another reduction
+    # length — equal to fp32 rounding, not bitwise (see TAP_RTOL)
     x = jax.random.normal(jax.random.PRNGKey(10), (1, 8, 8, 64), jnp.float32)
     y4 = ops.sparse_conv2d_pattern(x, b4, kh=3, kw=3)
     y8 = ops.sparse_conv2d_pattern(x, b8, kh=3, kw=3)
-    np.testing.assert_array_equal(np.asarray(y4), np.asarray(y8))
+    np.testing.assert_allclose(np.asarray(y4), np.asarray(y8),
+                               rtol=TAP_RTOL, atol=TAP_RTOL)
 
 
 def test_pack_taps_cache_key_separation():

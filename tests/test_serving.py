@@ -66,14 +66,30 @@ def test_engine_bit_identical_to_generate(family):
                                       for r in eng.requests)
 
 
+def test_compile_model_refuses_unlowerable_blocks_on_tpu(monkeypatch):
+    """On a TPU backend, the smoke widths' (16, 16) blocks cannot lower, so
+    ``compile_model`` raises before packing anything — the layout is never
+    packed and then interpreted.  Published widths get (128, 128)."""
+    from repro.launch.serve import sparse_spec
+    params, cfg = _lm(SMOKE["dense"])
+    spec = sparse_spec(cfg)
+    masks = RW.magnitude_block_masks(params, spec, None, rate=0.6)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="cannot be served on TPU"):
+        compile_model(apply_masks(params, masks), masks, spec,
+                      spec=CompileSpec(keep_dense=False))
+    assert {c.block for _, c in sparse_spec(configs.get("yi-9b"))} == \
+        {(128, 128)}
+
+
 def test_engine_packed_kernel_path():
     """The oracle holds on compile_model-packed params — the batched
     launch hits the real Pallas BCS kernels, not a dense fallback."""
     params, cfg = _lm(SMOKE["dense"])
-    from repro.launch.serve import SPARSE_SPEC
-    masks = RW.magnitude_block_masks(params, SPARSE_SPEC, None, rate=0.6)
+    from repro.launch.serve import sparse_spec
+    masks = RW.magnitude_block_masks(params, sparse_spec(cfg), None, rate=0.6)
     params = apply_masks(params, masks)
-    params, _ = compile_model(params, masks, SPARSE_SPEC,
+    params, _ = compile_model(params, masks, sparse_spec(cfg),
                               spec=CompileSpec(keep_dense=False))
     _assert_engine_matches_oracle(params, cfg, _prompts(cfg, [9, 6]), 5,
                                   n_slots=2)
