@@ -219,10 +219,10 @@ def _m_tile(M, bm, dtype):
     return bm, ((M + bm - 1) // bm) * bm
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("bm", "act", "interpret", "out_dtype"))
+@functools.partial(jax.jit, static_argnames=("bm", "act", "interpret",
+                                             "out_dtype", "name"))
 def bsr_matmul(x, values, k_idx, bias=None, scales=None, *, bm=128,
-               act="none", interpret=None, out_dtype=None):
+               act="none", interpret=None, out_dtype=None, name=None):
     """x (M, K) @ BCS-sparse W (K, N) -> (M, N).
 
     values (Nb, L, bk, bn); k_idx (Nb, L) int32.  ``scales`` rides along
@@ -231,7 +231,10 @@ def bsr_matmul(x, values, k_idx, bias=None, scales=None, *, bm=128,
     dot (int8 does not lower for TPU yet, see ``tpu_refusal``).
     ``interpret=None`` follows the backend (Pallas lowering on TPU,
     interpreter elsewhere).  ``out_dtype`` defaults to x.dtype; pass
-    jnp.float32 to keep the fp32 accumulator precision on a bf16 input."""
+    jnp.float32 to keep the fp32 accumulator precision on a bf16 input.
+    ``name`` (a projection's, e.g. "wq") names the launch
+    ``bsr_matmul_<name>`` in the compiled program and so in a profile;
+    without it the launch is ``bsr_matmul``."""
     M, K = x.shape
     Nb, L, bk, bn = values.shape
     N = Nb * bn
@@ -282,12 +285,13 @@ def bsr_matmul(x, values, k_idx, bias=None, scales=None, *, bm=128,
         ),
         out_shape=jax.ShapeDtypeStruct((Mp, N), out_dtype),
         interpret=interpret,
+        name=f"bsr_matmul_{name}" if name else None,
     )(k_idx, *args)
     return y[:M] if Mp != M else y
 
 
 def bsr_matmul_packed(x, layout, bias=None, *, bm=128, act="none",
-                      interpret=None, out_dtype=None):
+                      interpret=None, out_dtype=None, name=None):
     """x (M, K) @ PackedLayout W (K, N) -> (M, N).
 
     One ``bsr_matmul`` launch per degree bin — each bin's columns are padded
@@ -304,15 +308,18 @@ def bsr_matmul_packed(x, layout, bias=None, *, bm=128, act="none",
 
     Tensor-parallel layouts (``layout.n_shards > 0``) dispatch to
     ``bsr_matmul_sharded`` — callers never need to care which they hold.
+    Every bin's launch carries the same ``name`` (see ``bsr_matmul``).
     """
     if layout.n_shards:
         return bsr_matmul_sharded(x, layout, bias=bias, bm=bm, act=act,
-                                  interpret=interpret, out_dtype=out_dtype)
+                                  interpret=interpret, out_dtype=out_dtype,
+                                  name=name)
     if _device_mesh() is not None:
         # a replicated layout in a multi-device program: every device runs
         # the whole launch on the replicated operands
         call = functools.partial(bsr_matmul_packed, bm=bm, act=act,
-                                 interpret=interpret, out_dtype=out_dtype)
+                                 interpret=interpret, out_dtype=out_dtype,
+                                 name=name)
         return jax.shard_map(call, in_specs=P(), out_specs=P(),
                              check_vma=False)(x, layout, bias)
     outs = []
@@ -321,7 +328,7 @@ def bsr_matmul_packed(x, layout, bias=None, *, bm=128, act="none",
                                             layout.bin_bias(bias)):
         outs.append(bsr_matmul(x, vals_b, kidx_b, bias=bias_b, scales=sc_b,
                                bm=bm, act=act, interpret=interpret,
-                               out_dtype=out_dtype))
+                               out_dtype=out_dtype, name=name))
     y = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=-1)
     return layout.unpermute_cols(y)
 
@@ -378,7 +385,7 @@ def _sharded_launch(x, layout, bias, launch):
 
 
 def bsr_matmul_sharded(x, layout, bias=None, *, bm=128, act="none",
-                       interpret=None, out_dtype=None):
+                       interpret=None, out_dtype=None, name=None):
     """x (M, K) @ tensor-parallel PackedLayout (K, N) -> (M, N).
 
     Each shard runs the same per-bin ``bsr_matmul`` launches as
@@ -388,7 +395,8 @@ def bsr_matmul_sharded(x, layout, bias=None, *, bm=128, act="none",
     mechanics."""
     def launch(xx, vals, kidx, bias_b, sc_b):
         return bsr_matmul(xx, vals, kidx, bias=bias_b, scales=sc_b, bm=bm,
-                          act=act, interpret=interpret, out_dtype=out_dtype)
+                          act=act, interpret=interpret, out_dtype=out_dtype,
+                          name=name)
     return _sharded_launch(x, layout, bias, launch)
 
 

@@ -287,19 +287,20 @@ def clear_pack_cache():
 
 
 def sparse_linear(x, packed: PackedLayout | None = None, w=None, mask=None,
-                  bias=None, act="none", bm=128, interpret=None):
+                  bias=None, act="none", bm=128, interpret=None, name=None):
     """x (..., K) -> (..., N) through whichever path applies.
 
     With ``packed`` (a PackedLayout) the Pallas BCS kernel always runs —
     one launch per degree bin, outputs gathered back to original column
     order (ragged leading dims are flattened; ragged M is padded inside
+    ``bsr_matmul``), each launch named after ``name`` (see
     ``bsr_matmul``).  ``interpret=None`` auto-detects the backend."""
     lead = x.shape[:-1]
     K = x.shape[-1]
     x2 = x.reshape(-1, K)
     if packed is not None:
         y = bsr_matmul_packed(x2, packed, bias=bias, bm=bm, act=act,
-                              interpret=interpret)
+                              interpret=interpret, name=name)
     else:
         y = ref.masked_matmul_ref(
             x2, w, mask if mask is not None else jnp.ones_like(w),

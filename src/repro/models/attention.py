@@ -27,8 +27,9 @@ NEG_INF = -1e30
 def _proj(params, name, x, masks):
     """One attention projection.  ``layers.linear`` owns the dispatch:
     packed BCS layers route to the sparse kernel (and ignore the mask —
-    it is baked into the layout); dense layers apply it."""
-    return L.linear(params[name], x, masks.get(name))
+    it is baked into the layout), whose launches take the projection's
+    ``name``; dense layers apply it."""
+    return L.linear(params[name], x, masks.get(name), name=name)
 
 
 def attn_init(key, d_model, n_heads, n_kv, head_dim, dtype=jnp.bfloat16,

@@ -61,7 +61,7 @@ def _apply_act(y, act):
     return y
 
 
-def linear(params, x, mask=None, act="none"):
+def linear(params, x, mask=None, act="none", name=None):
     """y = act(x @ W + b) through whichever executor applies.
 
     If the layer carries a packed BCS layout (``params["packed"]``, a
@@ -74,6 +74,9 @@ def linear(params, x, mask=None, act="none"):
     pruning ``mask`` broadcastable to w (XLA fuses the multiply into the
     matmul operand).
 
+    ``name`` (the projection's, e.g. "wq") names the packed kernel's
+    launches ``bsr_matmul_<name>`` in a profile; the dense path ignores it.
+
     A ``core.packed.DegradedLayer`` sentinel (left by
     ``serve.compile.degrade_invalid_layers`` where a layout failed
     validation) routes to the dense einsum: the retained ``w`` carries the
@@ -85,7 +88,7 @@ def linear(params, x, mask=None, act="none"):
     if packed is not None:
         from repro.kernels import ops  # late import: kernels -> core only
         return ops.sparse_linear(x, packed=packed, bias=params.get("b"),
-                                 act=act)
+                                 act=act, name=name)
     w = params["w"]
     if mask is not None:
         w = w * mask.astype(w.dtype)
@@ -143,9 +146,9 @@ def ffn(params, x, masks=None):
     the output rounding (one rounding instead of two) — packed and dense
     outputs may differ by ~1 bf16 ulp; in fp32 they agree tightly."""
     m = masks or {}
-    g = linear(params["gate"], x, m.get("gate"), act="silu")
-    u = linear(params["up"], x, m.get("up"))
-    return linear(params["down"], g * u, m.get("down"))
+    g = linear(params["gate"], x, m.get("gate"), act="silu", name="gate")
+    u = linear(params["up"], x, m.get("up"), name="up")
+    return linear(params["down"], g * u, m.get("down"), name="down")
 
 
 # -- Depthwise causal conv1d (mamba/hymba mixers; NOT pruned per paper §5.2.4) --

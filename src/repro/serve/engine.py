@@ -20,6 +20,7 @@ from repro.models import attention as A
 from repro.models import ssm as S
 from repro.models import transformer as T
 from repro.serve import kvcache as KV
+from repro.serve import trace as TR
 from repro.serve.scheduler import (REASON_DEADLINE_EXPIRED, REASON_OVER_BUDGET,
                                    REASON_QUARANTINED, Request, Scheduler)
 
@@ -259,6 +260,20 @@ class ServingEngine:
     rejected requests, quarantined slots, expired deadlines, degraded
     layers, emitted tokens, and the running occupancy sum
     (``mean_occupancy()`` = mean fraction of busy slots per step).
+
+    Host spans (``serve.trace.span``, on the profiler's clock while a
+    trace runs; a request's spans share its ``rid``):
+
+    * ``serve.submit`` (``rid``, ``prompt_len``): the whole of ``submit``;
+    * ``serve.step`` (``index`` = ``stats["steps"]`` at entry, ``active``
+      = slots holding a request at entry): the whole of ``step``;
+    * ``serve.admit`` (``rid``, ``slot``, ``prompt_len``), inside
+      ``serve.step``, one per admitted request: from the prompt's transfer
+      through the B=1 prefill and the first token's readback to the
+      dispatch of the slot write;
+    * ``serve.harvest`` (``active``), inside ``serve.step``: from the
+      moment the step's tokens and finite flags are on the host to the end
+      of the token append and release loop.
     """
 
     FAMILIES = ("dense", "moe", "ssm", "hybrid")
@@ -311,38 +326,42 @@ class ServingEngine:
         bound the resubmission policy when the scheduler's ``max_queue``
         is full.
         """
-        req = Request(self._rid, tuple(int(t) for t in prompt),
-                      int(max_new_tokens), arrival=arrival,
-                      stop_token=stop_token, deadline_steps=deadline_steps,
-                      queue_ttl=queue_ttl, retries=retries, backoff=backoff)
-        self._rid += 1
-        self.requests[req.rid] = req
-        if (not req.prompt or req.max_new_tokens < 1
-                or KV.slot_capacity(self.cfg, len(req.prompt))
-                > self.seq_cap):
-            self.sched.reject(req, REASON_OVER_BUDGET)
-            self.stats["rejected"] += 1
-        else:
-            if self.sched.submit(req, self.stats["steps"]) == "rejected":
+        with TR.span("serve.submit", rid=self._rid, prompt_len=len(prompt)):
+            req = Request(self._rid, tuple(int(t) for t in prompt),
+                          int(max_new_tokens), arrival=arrival,
+                          stop_token=stop_token,
+                          deadline_steps=deadline_steps, queue_ttl=queue_ttl,
+                          retries=retries, backoff=backoff)
+            self._rid += 1
+            self.requests[req.rid] = req
+            if (not req.prompt or req.max_new_tokens < 1
+                    or KV.slot_capacity(self.cfg, len(req.prompt))
+                    > self.seq_cap):
+                self.sched.reject(req, REASON_OVER_BUDGET)
                 self.stats["rejected"] += 1
-        return req.rid
+            else:
+                if self.sched.submit(req, self.stats["steps"]) == "rejected":
+                    self.stats["rejected"] += 1
+            return req.rid
 
     # -- engine loop --------------------------------------------------------
 
     def _admit(self):
         while (pair := self.sched.admit(self.stats["steps"])) is not None:
             slot, req = pair
-            toks = jnp.asarray(np.asarray(req.prompt, np.int32)[None])
-            logits, rc = _jit_prefill(self.cfg, self.dist)(
-                self.params, toks, None)
-            t0 = int(jnp.argmax(logits[:, -1, :], axis=-1)[0])
-            req.tokens.append(t0)
-            self.stats["admitted"] += 1
-            self.stats["tokens"] += 1
-            if req.done():      # budget of 1 (or instant stop token)
-                self._release(slot, req, "finished")
-                continue
-            self.cache = KV.write_prefill(self.cache, slot, rc)
+            with TR.span("serve.admit", rid=req.rid, slot=slot,
+                         prompt_len=len(req.prompt)):
+                toks = jnp.asarray(np.asarray(req.prompt, np.int32)[None])
+                logits, rc = _jit_prefill(self.cfg, self.dist)(
+                    self.params, toks, None)
+                t0 = int(jnp.argmax(logits[:, -1, :], axis=-1)[0])
+                req.tokens.append(t0)
+                self.stats["admitted"] += 1
+                self.stats["tokens"] += 1
+                if req.done():      # budget of 1 (or instant stop token)
+                    self._release(slot, req, "finished")
+                    continue
+                self.cache = KV.write_prefill(self.cache, slot, rc)
             self.cap[slot] = KV.slot_capacity(self.cfg, len(req.prompt))
             self.pos[slot] = len(req.prompt)
             self.tok[slot] = t0
@@ -382,17 +401,26 @@ class ServingEngine:
         never appended; neighbors are untouched).  Returns the number of
         active slots stepped (0 = an idle tick while the open-loop queue
         waits to arrive)."""
-        self._sweep_faults()
-        self._admit()
-        active = self.sched.active()
-        self.stats["steps"] += 1
-        self.stats["occupancy_sum"] += len(active) / self.n_slots
-        if not active:
-            return 0
-        nxt, ok, self.cache = self._step_fn(
-            self.params, jnp.asarray(self.tok), self.cache,
-            jnp.asarray(self.pos), jnp.asarray(self.cap))
-        nxt, ok = np.asarray(nxt), np.asarray(ok)
+        with TR.span("serve.step", index=self.stats["steps"],
+                     active=len(self.sched.active())):
+            self._sweep_faults()
+            self._admit()
+            active = self.sched.active()
+            self.stats["steps"] += 1
+            self.stats["occupancy_sum"] += len(active) / self.n_slots
+            if not active:
+                return 0
+            nxt, ok, self.cache = self._step_fn(
+                self.params, jnp.asarray(self.tok), self.cache,
+                jnp.asarray(self.pos), jnp.asarray(self.cap))
+            nxt, ok = np.asarray(nxt), np.asarray(ok)
+            with TR.span("serve.harvest", active=len(active)):
+                self._harvest(active, nxt, ok)
+            return len(active)
+
+    def _harvest(self, active, nxt, ok):
+        """Append each stepped slot's token, or quarantine the slot when
+        its logits came back non-finite; release finished requests."""
         for slot, req in active:
             if not bool(ok[slot]):
                 self._release(slot, req, "quarantined",
@@ -405,7 +433,6 @@ class ServingEngine:
             self.tok[slot] = t
             if req.done():
                 self._release(slot, req, "finished")
-        return len(active)
 
     def run(self, max_steps=100_000):
         """Drive ``step`` until queue and slots drain; returns ``stats``.
