@@ -43,7 +43,7 @@ kernel paths.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 
 import numpy as np
 import jax
@@ -340,6 +340,69 @@ class PackedLayout:
                         dense[int(kidx[j, l]), oj] += vals[j, l]
                 col += vals.shape[0]
         return dense.transpose(0, 2, 1, 3).reshape(K, N)
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclass(frozen=True, eq=False)
+class LayerSlice:
+    """One degree bin's values of a whole layer stack, ``stack``
+    (n_layers, nb_b, L_b, bk, bn), standing for its slice at layer
+    ``index`` (an int32 scalar).  A layer scan puts it in a layout in place
+    of the sliced bin (``layer_views``) and the kernel reads the stack at
+    that layer itself, so the layer's weights are never copied out of the
+    stack (``kernels.bsr_matmul.bsr_matmul``'s ``layer``)."""
+
+    stack: object
+    index: object
+
+    @property
+    def shape(self) -> tuple:
+        """The layer's bin shape, (nb_b, L_b, bk, bn)."""
+        return self.stack.shape[1:]
+
+    def tree_flatten(self):
+        """Flatten into (array leaves, no aux) for jax pytree traversal."""
+        return (self.stack, self.index), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        """Rebuild from ``tree_flatten`` output (jax protocol)."""
+        return cls(*children)
+
+
+def _layer_stacked(node) -> bool:
+    """A single-device layout stacked over layers alone: values
+    (n_layers, nb_b, L_b, bk, bn).  Expert and sharded layouts keep their
+    extra axes in the slice and are sliced as before."""
+    return (isinstance(node, PackedLayout) and not node.n_shards
+            and node.values[0].ndim == 5)
+
+
+def layer_views(xs):
+    """Prepare the per-layer params ``xs`` of a ``lax.scan`` so that no
+    layout's weights are sliced: ``(xs', view)``, where ``xs'`` holds each
+    layer-stacked layout with its value bins replaced by the layer indices
+    ``arange(n_layers)``, and ``view(x_i)``, applied to the scan's slice of
+    ``xs'``, puts back every bin as a ``LayerSlice`` of the whole stack at
+    that layer (the identity where ``xs`` holds no such layout)."""
+    is_layout = lambda n: isinstance(n, PackedLayout)  # noqa: E731
+
+    def indices(n):
+        if not _layer_stacked(n):
+            return n
+        return replace(n, values=tuple(jnp.arange(v.shape[0], dtype=jnp.int32)
+                                       for v in n.values))
+
+    def put_back(n, full):
+        if not _layer_stacked(full):
+            return n
+        return replace(n, values=tuple(LayerSlice(v, i) for v, i in
+                                       zip(full.values, n.values)))
+
+    def view(x_i):
+        return jax.tree_util.tree_map(put_back, x_i, xs, is_leaf=is_layout)
+
+    return jax.tree_util.tree_map(indices, xs, is_leaf=is_layout), view
 
 
 @jax.tree_util.register_pytree_node_class

@@ -3,16 +3,24 @@
 The TPU executor for the paper's compiler contribution (§4.3): the grid
 iterates ONLY over surviving weight blocks — pruned blocks are never read
 from HBM nor multiplied.  The block-column index array is scalar-prefetched
-(SMEM) and drives the x BlockSpec index_map, the TPU analogue of
-PatDNN-style sparsity-baked codegen.
+(SMEM) and drives the in-kernel slice of x each live block multiplies, the
+TPU analogue of PatDNN-style sparsity-baked codegen.
 
 Layout (from repro.core.bcs.pad_to_uniform_csc):
   values (Nb, L, bk, bn)  surviving blocks per output column, zero-padded
   k_idx  (Nb, L) int32    K-block index each slot reads from
-Grid: (M/bm, Nb, L) — L innermost so the fp32 VMEM accumulator tile is
-revisited; equal trip counts per (i, j) = the load-balance analogue of the
-paper's row reordering.  Epilogue (bias + activation) fuses into the final
-store (layer-fusion analogue, §A.1).
+Grid: (M/bm, Nb) — one step per (M tile, block column).  The (bm, K) x
+tile's block index depends on the M tile alone, so it is fetched once per
+M tile and stays in VMEM across the columns; each step fetches its whole
+(L, bk, bn) weight column in one DMA and runs its L live blocks in order
+(unrolled), slicing each block's (bm, bk) x columns out of the resident
+tile at ``k_idx`` (scalar-prefetched) into an fp32 VMEM accumulator.
+Equal trip counts per column = the load-balance analogue of the paper's
+row reordering.  ``bm`` follows the shapes (``m_tile``): the largest of
+512/256/128 whose pipelined blocks fit ``VMEM_BUDGET`` once M reaches
+512, else M itself rounded to the sublane tile (decode).  Epilogue
+(bias + activation) fuses into the step's store (layer-fusion analogue,
+§A.1).
 
 Accumulation is always fp32 (``preferred_element_type`` on the MXU dot +
 fp32 VMEM scratch); bf16 inputs therefore take the mixed-precision path —
@@ -30,11 +38,11 @@ and gathers outputs back to original column order in the epilogue.
 
 ``tap_gather_conv`` (bottom of this file) is the second kernel: the
 executor for pattern/connectivity-pruned convolutions, consuming the
-``core.packed.TapLayout`` sibling format.  Where the BCS grid pays one
-step per surviving BLOCK, per-kernel pattern masks have no block
-structure, so that grid shape would cost one step per scalar tap; the tap
-kernel instead keeps the alive im2col band VMEM-resident and gathers each
-output filter's surviving taps in one (M tile, filter group) step.
+``core.packed.TapLayout`` sibling format.  Per-kernel pattern masks have
+no block structure, so a (1, group) block would make each BCS dot a single
+tap; the tap kernel instead keeps the alive im2col band VMEM-resident and
+gathers each output filter's surviving taps in one (M tile, filter group)
+step.
 
 ``bsr_conv2d_implicit`` / ``tap_gather_conv_implicit`` are the
 implicit-GEMM conv variants of both: instead of consuming a pre-extracted
@@ -60,34 +68,38 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from repro.core import bcs as BCS
+from repro.core.packed import LayerSlice
 
 
-def _kernel(k_idx, x_ref, w_ref, s_ref, b_ref, o_ref, acc_ref, *, n_l, act):
-    l = pl.program_id(2)
-
-    @pl.when(l == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    w = w_ref[0, 0]
-    if s_ref is not None:
-        # int8 path: dequantize in-kernel (one fp32 scale per stored block
-        # or per block column) BEFORE the dot, so accumulation stays fp32
-        # and the result equals the dequantized dense reference
-        w = w.astype(jnp.float32) * s_ref[0, 0]
-    acc_ref[...] += jnp.dot(x_ref[...], w,
-                            preferred_element_type=jnp.float32)
-
-    @pl.when(l == n_l - 1)
-    def _store():
-        out = acc_ref[...]
-        if b_ref is not None:
-            out = out + b_ref[0].astype(jnp.float32)
-        if act == "silu":
-            out = out * jax.nn.sigmoid(out)
-        elif act == "relu":
-            out = jnp.maximum(out, 0.0)
-        o_ref[...] = out.astype(o_ref.dtype)
+def _kernel(k_idx, x_ref, w_ref, s_ref, b_ref, o_ref, acc_ref, *, act):
+    """One (M tile, block column) step: the column's live blocks in order
+    l = 0 .. L-1, each a (bm, bk) @ (bk, bn) dot into the fp32
+    accumulator, then the epilogue.  The loop over l is unrolled (L is
+    static), so the scheduler overlaps one block's loads with the
+    previous block's dot; a ``fori_loop`` took 1.4x as long on the v5e."""
+    j = pl.program_id(1)
+    _, _, n_l, bk, _ = w_ref.shape
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    for l in range(n_l):
+        k0 = pl.multiple_of(k_idx[j, l] * bk, bk)
+        w = w_ref[0, 0, l]
+        if s_ref is not None:
+            # int8 path: dequantize in-kernel (one fp32 scale per stored
+            # block or per block column) BEFORE the dot, so accumulation
+            # stays fp32 and the result equals the dequantized dense
+            # reference
+            w = w.astype(jnp.float32) * s_ref[0, l if s_ref.shape[1] > 1
+                                              else 0]
+        acc_ref[...] += jnp.dot(x_ref[:, pl.ds(k0, bk)], w,
+                                preferred_element_type=jnp.float32)
+    out = acc_ref[...]
+    if b_ref is not None:
+        out = out + b_ref[0].astype(jnp.float32)
+    if act == "silu":
+        out = out * jax.nn.sigmoid(out)
+    elif act == "relu":
+        out = jnp.maximum(out, 0.0)
+    o_ref[...] = out.astype(o_ref.dtype)
 
 
 # TPU vreg lane width: Mosaic needs the minor dim of every block to be a
@@ -98,7 +110,7 @@ LANE = 128
 # Kernels the TPU compiler refuses today, with Mosaic's reason (each one is
 # a strict xfail in tests/test_tpu_compile.py; ROADMAP S2).
 _REFUSED = {
-    "int8": "int8 bsr_matmul: its (1, 1) scale BlockSpec is neither "
+    "int8": "int8 bsr_matmul: its (1, L) scale BlockSpec is neither "
             "(8, 128)-aligned nor the full scale array",
     "tap": "tap_gather_conv: its (bm, group) output block is not "
            "lane-aligned at the packed group=1",
@@ -114,10 +126,10 @@ def tpu_refusal(kind, block=None, shape=None, value_dtype=None):
 
     ``kind`` is "bcs" (``bsr_matmul`` over a (bk, bn) ``block``-ed layout
     of a (K, N) weight ``shape`` holding ``value_dtype`` values), "tap",
-    "conv_implicit" or "tap_implicit".  ``bsr_matmul`` tiles x as (bm, bk)
-    and the bias/output as (1 | bm, bn): Mosaic needs bk and bn to be
-    multiples of ``LANE`` or the whole K / N, and refuses the int8 path's
-    (1, 1) scale blocks."""
+    "conv_implicit" or "tap_implicit".  ``bsr_matmul`` slices (bm, bk) x
+    blocks out of its (bm, K) tile and tiles the bias/output as
+    (1 | bm, bn): Mosaic needs bk and bn to be multiples of ``LANE`` or
+    the whole K / N, and refuses the int8 path's (1, L) scale blocks."""
     if kind != "bcs":
         return _REFUSED[kind]
     if value_dtype is not None and jnp.dtype(value_dtype) == jnp.int8:
@@ -219,16 +231,54 @@ def _m_tile(M, bm, dtype):
     return bm, ((M + bm - 1) // bm) * bm
 
 
+# VMEM of one bsr_matmul launch.  The v5e has 128 MiB; the tile choice
+# keeps the pipelined blocks within VMEM_BUDGET, and the launch lets
+# Mosaic use what they take plus _VMEM_HEADROOM for its own scratch.
+VMEM_BUDGET = 64 * 2**20
+_VMEM_HEADROOM = 16 * 2**20
+# M tiles tried, largest first, once M reaches the first of them; below
+# it the tile is M itself (rounded up to the sublane tile by _m_tile)
+_BM_CHOICES = (512, 256, 128)
+
+
+def vmem_bytes(bm, K, L, bk, bn, itemsize=2, w_itemsize=2, out_itemsize=2):
+    """VMEM the pipelined blocks of one ``bsr_matmul`` launch take: the
+    (bm, K) x tile, the (L, bk, bn) weight column and the (bm, bn) output
+    tile, each double-buffered, and the fp32 accumulator."""
+    return (2 * bm * K * itemsize + 2 * L * bk * bn * w_itemsize
+            + 2 * bm * bn * out_itemsize + bm * bn * 4)
+
+
+def m_tile(M, K, L=1, bk=LANE, bn=LANE, itemsize=2, w_itemsize=2,
+           out_itemsize=2):
+    """The M tile ``bsr_matmul`` takes for an (M, K) x and an (L, bk, bn)
+    weight column when it is given none: at M >= 512 the largest of
+    ``_BM_CHOICES`` whose ``vmem_bytes`` fit ``VMEM_BUDGET`` (the
+    smallest if none does), else 128 — which ``_m_tile`` then cuts to M
+    (decode, M = 16, runs one 16-row tile)."""
+    if M >= _BM_CHOICES[0]:
+        for bm in _BM_CHOICES:
+            if vmem_bytes(bm, K, L, bk, bn, itemsize, w_itemsize,
+                          out_itemsize) <= VMEM_BUDGET:
+                return bm
+    return _BM_CHOICES[-1]
+
+
 @functools.partial(jax.jit, static_argnames=("bm", "act", "interpret",
                                              "out_dtype", "name"))
-def bsr_matmul(x, values, k_idx, bias=None, scales=None, *, bm=128,
-               act="none", interpret=None, out_dtype=None, name=None):
+def bsr_matmul(x, values, k_idx, bias=None, scales=None, layer=0, *,
+               bm=None, act="none", interpret=None, out_dtype=None,
+               name=None):
     """x (M, K) @ BCS-sparse W (K, N) -> (M, N).
 
-    values (Nb, L, bk, bn); k_idx (Nb, L) int32.  ``scales`` rides along
+    values (Nb, L, bk, bn), or a layer stack (n_layers, Nb, L, bk, bn) of
+    which the launch reads layer ``layer`` (an int32 scalar) in place
+    (``core.packed.LayerSlice``); k_idx (Nb, L) int32.  ``scales`` rides
+    along
     for int8 values (``core.quant``): fp32, (Nb, L) per-block or (Nb,)
     per-block-column, dequantized in-kernel before the fp32-accumulated
     dot (int8 does not lower for TPU yet, see ``tpu_refusal``).
+    ``bm=None`` takes the M tile from the shapes (``m_tile``).
     ``interpret=None`` follows the backend (Pallas lowering on TPU,
     interpreter elsewhere).  ``out_dtype`` defaults to x.dtype; pass
     jnp.float32 to keep the fp32 accumulator precision on a bf16 input.
@@ -236,61 +286,70 @@ def bsr_matmul(x, values, k_idx, bias=None, scales=None, *, bm=128,
     ``bsr_matmul_<name>`` in the compiled program and so in a profile;
     without it the launch is ``bsr_matmul``."""
     M, K = x.shape
-    Nb, L, bk, bn = values.shape
+    # unstacked values are a stack of one, read at layer 0
+    values = values.reshape((-1,) + values.shape[-4:])
+    _, Nb, L, bk, bn = values.shape
     N = Nb * bn
     interpret = _interpret_mode(interpret, refusal_here(
         "bcs", (bk, bn), (K, N), values.dtype))
-    bm, Mp = _m_tile(M, bm, x.dtype)
     assert K % bk == 0, (K, bk)
-    if Mp != M:
-        x = jnp.pad(x, ((0, Mp - M), (0, 0)))
     if out_dtype is None:
         out_dtype = x.dtype
+    sizes = (x.dtype.itemsize, values.dtype.itemsize,
+             jnp.dtype(out_dtype).itemsize)
+    if bm is None:
+        bm = m_tile(M, K, L, bk, bn, *sizes)
+    bm, Mp = _m_tile(M, bm, x.dtype)
+    if Mp != M:
+        x = jnp.pad(x, ((0, Mp - M), (0, 0)))
 
-    grid = (Mp // bm, Nb, L)
+    grid = (Mp // bm, Nb)
     in_specs = [
-        pl.BlockSpec((bm, bk), lambda i, j, l, kidx: (i, kidx[j, l])),
-        pl.BlockSpec((1, 1, bk, bn), lambda i, j, l, kidx: (j, l, 0, 0)),
+        pl.BlockSpec((bm, K), lambda i, j, *_: (i, 0)),
+        pl.BlockSpec((1, 1, L, bk, bn),
+                     lambda i, j, kidx, lyr: (lyr[0], j, 0, 0, 0)),
     ]
     args = [x, values]
     if scales is not None:
+        # per-block scales ride as a (1, L) row per column, per-column
+        # scales as a (1, 1) block
         sc = scales if scales.ndim == 2 else scales[:, None]
-        # per-block scales index (j, l); per-column scales are constant
-        # across the degree steps and index (j, 0)
-        idx = ((lambda i, j, l, kidx: (j, l)) if sc.shape[1] == L
-               else (lambda i, j, l, kidx: (j, 0)))
-        in_specs.append(pl.BlockSpec((1, 1), idx))
+        in_specs.append(pl.BlockSpec((1, sc.shape[1]),
+                                     lambda i, j, *_: (j, 0)))
         args.append(sc)
     if bias is not None:
-        in_specs.append(pl.BlockSpec((1, bn), lambda i, j, l, kidx: (0, j)))
+        in_specs.append(pl.BlockSpec((1, bn), lambda i, j, *_: (0, j)))
         args.append(bias.reshape(1, N))
     has_s, has_b = scales is not None, bias is not None
 
-    def kern(k_idx_ref, x_ref, w_ref, *rest):
+    def kern(k_idx_ref, layer_ref, x_ref, w_ref, *rest):
         rest = list(rest)
         s_ref = rest.pop(0) if has_s else None
         b_ref = rest.pop(0) if has_b else None
         o_ref, acc_ref = rest
         _kernel(k_idx_ref, x_ref, w_ref, s_ref, b_ref, o_ref, acc_ref,
-                n_l=L, act=act)
+                act=act)
 
     y = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=grid,
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((bm, bn), lambda i, j, l, kidx: (i, j)),
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, *_: (i, j)),
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((Mp, N), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_bytes(bm, K, L, bk, bn, *sizes)
+            + _VMEM_HEADROOM),
         interpret=interpret,
         name=f"bsr_matmul_{name}" if name else None,
-    )(k_idx, *args)
+    )(k_idx, jnp.reshape(layer, (1,)).astype(jnp.int32), *args)
     return y[:M] if Mp != M else y
 
 
-def bsr_matmul_packed(x, layout, bias=None, *, bm=128, act="none",
+def bsr_matmul_packed(x, layout, bias=None, *, bm=None, act="none",
                       interpret=None, out_dtype=None, name=None):
     """x (M, K) @ PackedLayout W (K, N) -> (M, N).
 
@@ -301,7 +360,7 @@ def bsr_matmul_packed(x, layout, bias=None, *, bm=128, act="none",
     order first); the final column gather restores the original output
     order.  Per-column accumulation order is identical to the single-bin
     kernel (padding blocks add exact zeros at the END of each column's
-    sequential grid accumulation), so reordered and unreordered results
+    sequential accumulation), so reordered and unreordered results
     are bit-identical.
     Quantized layouts (int8 values, ``core.quant``) thread each bin's
     ``scales`` leaf into the launch for in-kernel dequantization.
@@ -326,9 +385,13 @@ def bsr_matmul_packed(x, layout, bias=None, *, bm=128, act="none",
     for vals_b, kidx_b, sc_b, bias_b in zip(layout.values, layout.k_idx,
                                             layout.bin_scales(),
                                             layout.bin_bias(bias)):
+        layer = 0
+        if isinstance(vals_b, LayerSlice):
+            vals_b, layer = vals_b.stack, vals_b.index
         outs.append(bsr_matmul(x, vals_b, kidx_b, bias=bias_b, scales=sc_b,
-                               bm=bm, act=act, interpret=interpret,
-                               out_dtype=out_dtype, name=name))
+                               layer=layer, bm=bm, act=act,
+                               interpret=interpret, out_dtype=out_dtype,
+                               name=name))
     y = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=-1)
     return layout.unpermute_cols(y)
 
@@ -384,7 +447,7 @@ def _sharded_launch(x, layout, bias, launch):
     return layout.merge_shards(y)
 
 
-def bsr_matmul_sharded(x, layout, bias=None, *, bm=128, act="none",
+def bsr_matmul_sharded(x, layout, bias=None, *, bm=None, act="none",
                        interpret=None, out_dtype=None, name=None):
     """x (M, K) @ tensor-parallel PackedLayout (K, N) -> (M, N).
 

@@ -287,14 +287,16 @@ def clear_pack_cache():
 
 
 def sparse_linear(x, packed: PackedLayout | None = None, w=None, mask=None,
-                  bias=None, act="none", bm=128, interpret=None, name=None):
+                  bias=None, act="none", bm=None, interpret=None, name=None):
     """x (..., K) -> (..., N) through whichever path applies.
 
     With ``packed`` (a PackedLayout) the Pallas BCS kernel always runs —
     one launch per degree bin, outputs gathered back to original column
     order (ragged leading dims are flattened; ragged M is padded inside
     ``bsr_matmul``), each launch named after ``name`` (see
-    ``bsr_matmul``).  ``interpret=None`` auto-detects the backend."""
+    ``bsr_matmul``).  ``bm=None`` takes the M tile from the shapes
+    (``bsr_matmul.m_tile``); ``interpret=None`` auto-detects the
+    backend."""
     lead = x.shape[:-1]
     K = x.shape[-1]
     x2 = x.reshape(-1, K)
@@ -368,7 +370,7 @@ def _pick_implicit(implicit, x, kh, kw, stride, padding, bk=None):
 
 
 def sparse_conv2d(x, packed: PackedLayout, *, kh, kw, stride=1,
-                  padding="SAME", bias=None, act="none", bm=128,
+                  padding="SAME", bias=None, act="none", bm=None,
                   interpret=None, implicit=None):
     """x (B, H, W, Cin) * packed conv weight -> (B, Ho, Wo, Cout).
 
@@ -395,7 +397,8 @@ def sparse_conv2d(x, packed: PackedLayout, *, kh, kw, stride=1,
     if _pick_implicit(implicit, x, kh, kw, stride, padding,
                       bk=packed.block[0]):
         return bsr_conv2d_implicit(x, packed, kh=kh, kw=kw, stride=stride,
-                                   padding=padding, bias=bias, bm=bm,
+                                   padding=padding, bias=bias,
+                                   bm=bm or 128,
                                    act=act, interpret=interpret)
     patches = im2col(x, kh, kw, stride, padding)
     _, Ho, Wo, K = patches.shape
@@ -445,7 +448,7 @@ def sparse_conv2d_pattern(x, tap, *, kh, kw, stride=1, padding="SAME",
 
 
 def sparse_expert_linear(x, packed: PackedLayout, bias=None, act="none",
-                         bm=128, interpret=None):
+                         bm=None, interpret=None):
     """Batched per-expert sparse GEMM: x (E, M, K) -> (E, M, N).
 
     ``packed`` carries a leading expert axis on every leaf (values

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.core.packed import layer_views
 from repro.models import module as M
 from repro.models import layers as L
 from repro.models import attention as A
@@ -24,9 +25,13 @@ from repro.models.moe import moe, moe_init
 
 def maybe_scan(body, carry, xs, unroll=False):
     """lax.scan, or an unrolled Python loop when ``unroll`` (the dry-run's
-    cost-probe mode: XLA cost analysis counts while-loop bodies once)."""
+    cost-probe mode: XLA cost analysis counts while-loop bodies once).
+    The scan hands packed layouts to ``body`` as views of their layer
+    stacks (``core.packed.layer_views``): the kernels read each layer's
+    weights in place, where a sliced layout would be a copy per layer."""
     if not unroll:
-        return jax.lax.scan(body, carry, xs)
+        xs, view = layer_views(xs)
+        return jax.lax.scan(lambda c, x: body(c, view(x)), carry, xs)
     L = jax.tree_util.tree_leaves(xs)[0].shape[0]
     ys = []
     for i in range(L):

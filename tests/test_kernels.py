@@ -155,3 +155,114 @@ def test_tpu_block_refusal(block, shape, bad):
     assert why is None if bad is None else bad in why
     assert "int8" in BM.tpu_refusal("bcs", block, shape, jnp.int8)
     assert BM.refusal_here("bcs", block, shape) is None   # not a TPU
+
+
+def _per_block_loop(x, w, mask, bk, bn, bias=None, act="none"):
+    """The packed kernel's arithmetic spelled out: for each block column,
+    an fp32 accumulator that adds one (M, bk) @ (bk, bn) dot per live
+    block, in ascending K-block order, then the epilogue."""
+    K, N = w.shape
+    cols = []
+    for c in range(N // bn):
+        acc = jnp.zeros((x.shape[0], bn), jnp.float32)
+        for k in range(K // bk):
+            blk = (slice(k * bk, (k + 1) * bk), slice(c * bn, (c + 1) * bn))
+            if mask[blk].any():
+                acc = acc + jnp.dot(x[:, blk[0]], w[blk],
+                                    preferred_element_type=jnp.float32)
+        if bias is not None:
+            acc = acc + bias[c * bn:(c + 1) * bn].astype(jnp.float32)
+        if act == "silu":
+            acc = acc * jax.nn.sigmoid(acc)
+        cols.append(acc.astype(x.dtype))
+    return jnp.concatenate(cols, axis=1)
+
+
+@pytest.mark.parametrize("n_bins", [1, 4], ids=["one_bin", "reordered"])
+@pytest.mark.parametrize("fused", [False, True], ids=["plain", "bias_silu"])
+@pytest.mark.parametrize("M", [8, 16, 129, 1024])
+def test_packed_bitwise_per_block_loop(M, fused, n_bins):
+    """One grid step per (M tile, block column) gives, bit for bit, the
+    per-block accumulation: over M tiles (1024 rows run as two 512-row
+    tiles, 129 as two padded ones), over degree bins, through the fused
+    bias + silu, and in a column with no live block."""
+    bk = bn = 128
+    K, N = 4 * bk, 6 * bn
+    rng = np.random.default_rng(M)
+    keep = rng.random((K // bk, N // bn)) < 0.5
+    keep[:, 2] = False                                   # empty column
+    keep[:, 4] = True                                    # dense column
+    mask = np.kron(keep, np.ones((bk, bn), bool))
+    w = jnp.asarray(rng.standard_normal((K, N)) * mask, jnp.bfloat16)
+    x = jnp.asarray(rng.standard_normal((M, K)), jnp.bfloat16)
+    bias = (jnp.asarray(rng.standard_normal(N), jnp.bfloat16) if fused
+            else None)
+    act = "silu" if fused else "none"
+    lay = ops.pack(np.asarray(w), mask, (bk, bn), reorder=n_bins > 1,
+                   n_bins=n_bins, use_cache=False)
+    assert len(lay.values) == n_bins
+    y = ops.sparse_linear(x, packed=lay, bias=bias, act=act,
+                          interpret=True)
+    want = _per_block_loop(x, w, mask, bk, bn, bias, act)
+    assert y.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(y, np.float32),
+                                  np.asarray(want, np.float32))
+
+
+@pytest.mark.parametrize("K", [4096, 14336, 32768])
+@pytest.mark.parametrize("M", [16, 4096, 8192])
+def test_m_tile_within_vmem_budget(M, K):
+    """The M tile is a function of the shapes: decode runs one 16-row
+    tile, prefill the largest of 512/256/128 whose pipelined blocks, with
+    every block of the column live, fit the VMEM budget; the launch's
+    VMEM limit stays inside the v5e's 128 MiB."""
+    from repro.kernels import bsr_matmul as BM
+
+    L = K // BM.LANE
+    bm, Mp = BM._m_tile(M, BM.m_tile(M, K, L), jnp.bfloat16)
+    want = {16: 16}.get(M, 256 if K == 32768 else 512)
+    assert (bm, Mp % bm) == (want, 0)
+    need = BM.vmem_bytes(bm, K, L, BM.LANE, BM.LANE)
+    assert need <= BM.VMEM_BUDGET
+    assert need + BM._VMEM_HEADROOM <= 128 * 2**20
+    if bm < 512 and M >= 512:
+        # the next larger tile would not have fit
+        assert BM.vmem_bytes(2 * bm, K, L, BM.LANE, BM.LANE) > BM.VMEM_BUDGET
+
+
+@pytest.mark.parametrize("n_bins", [1, 2], ids=["one_bin", "reordered"])
+def test_layer_scan_reads_stacked_values(n_bins):
+    """A layer scan hands each packed layout to its body as views of the
+    layer stack (``LayerSlice``), so no layer's weights are sliced out;
+    the launches read the stack in place, bit for bit as the layer's own
+    layout, while every other leaf is sliced as before."""
+    from repro.core.packed import LayerSlice
+    from repro.models.transformer import maybe_scan
+
+    bk = bn = 128
+    K, N, n_layers = 2 * bk, 3 * bn, 3
+    rng = np.random.default_rng(n_bins)
+    keep = rng.random((K // bk, N // bn)) < 0.6
+    keep[:, 0] = True
+    mask = np.kron(keep, np.ones((bk, bn), bool))
+    lays = [ops.pack(np.asarray(jnp.asarray(rng.standard_normal((K, N))
+                                            * mask, jnp.bfloat16)),
+                     mask, (bk, bn), reorder=n_bins > 1, n_bins=n_bins,
+                     use_cache=False) for _ in range(n_layers)]
+    stack = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *lays)
+    x = jnp.asarray(rng.standard_normal((16, K)), jnp.bfloat16)
+    views = []
+
+    def body(c, p):
+        views.append(all(isinstance(v, LayerSlice) for v in p["w"].values))
+        return c, (p["i"], ops.sparse_linear(x, packed=p["w"],
+                                             interpret=True))
+
+    _, (idx, ys) = maybe_scan(body, None, {"w": stack,
+                                           "i": jnp.arange(n_layers) * 7})
+    assert views == [True]
+    np.testing.assert_array_equal(np.asarray(idx), np.arange(n_layers) * 7)
+    for layer, lay in enumerate(lays):
+        want = ops.sparse_linear(x, packed=lay, interpret=True)
+        np.testing.assert_array_equal(np.asarray(ys[layer], np.float32),
+                                      np.asarray(want, np.float32))

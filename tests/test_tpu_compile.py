@@ -6,7 +6,8 @@ tiling rules; these compiles do not.  Covered, at Yi-9B's published widths
 in bf16:
 
   * ``bsr_matmul_packed`` on the served projection shapes (wq, wk, gate/up,
-    down) at decode M=8 and prefill M=512, with and without the fused
+    down) at decode M=8 and prefill M=512, and at Mistral-7B-v0.1's
+    widths at prefill M=4096 and 8192, with and without the fused
     bias + silu epilogue;
   * the tensor-parallel ``bsr_matmul_sharded`` over a 4-chip mesh;
   * the engine's whole served step (``serve.engine._jit_serving_step``)
@@ -48,6 +49,9 @@ BLOCK = sparse_spec(CFG)[0][1].block
 # (K, N) of the served projections at Yi-9B widths
 PROJ = {"wq": (4096, 4096), "wk": (4096, 512), "gate": (4096, 11008),
         "down": (11008, 4096)}
+# ... and at Mistral-7B-v0.1's widths, which the benchmark serves
+MISTRAL_PROJ = {"wq": (4096, 4096), "wk": (4096, 1024),
+                "gate": (4096, 14336), "down": (14336, 4096)}
 DENSITY = 0.4      # magnitude_block_masks at rate 0.6 keeps 40% of blocks
 
 
@@ -120,11 +124,20 @@ def _compile(fn, *args):
 
 # -- the served kernel -------------------------------------------------------
 
+# (K, N) and M of each case: Yi-9B at decode M=8 and prefill M=512, then
+# Mistral-7B-v0.1 at the 4096- and 8192-token admission prefills, where
+# the (bm, K) x tile at K = 14336 (down) needs the raised VMEM limit
+MATMUL_CASES = (
+    [pytest.param(PROJ[p], M, id=f"{p}-{M}")
+     for p in sorted(PROJ) for M in (8, 512)]
+    + [pytest.param(MISTRAL_PROJ[p], M, id=f"mistral-{p}-{M}")
+       for p in sorted(MISTRAL_PROJ) for M in (4096, 8192)])
+
+
 @pytest.mark.parametrize("fused", [False, True], ids=["plain", "bias_silu"])
-@pytest.mark.parametrize("M", [8, 512])
-@pytest.mark.parametrize("proj", sorted(PROJ))
-def test_served_matmul_lowers(one_chip, proj, M, fused):
-    K, N = PROJ[proj]
+@pytest.mark.parametrize("shape,M", MATMUL_CASES)
+def test_served_matmul_lowers(one_chip, shape, M, fused):
+    K, N = shape
     x = _sds((M, K), jnp.bfloat16, one_chip)
     bias = _sds((N,), jnp.bfloat16, one_chip) if fused else None
     compiled = _compile(
@@ -198,12 +211,19 @@ def _step_lowered(params, sharding, dist=None, n_slots=8, seq_cap=512):
         params, col, cache, col, _sds((n_slots,), jnp.int32, sharding))
 
 
+def _weight_slices(text):
+    """Instructions that copy a layer's (..., 128, 128) weight bins out of
+    the layer stack: none, since the launches read the stack in place."""
+    return re.findall(r"%dynamic[-_]slice\S* = bf16\[[\d,]*,128,128\]", text)
+
+
 def test_served_step_lowers(one_chip, on_tpu, monkeypatch):
     """The engine's batched decode step (8 slots), the program every served
     token runs, compiles with the Pallas kernels in it."""
     monkeypatch.setattr(E, "_JIT_CACHE", type(E._JIT_CACHE)())
     compiled = _step_lowered(_served_params(one_chip), one_chip).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    assert not _weight_slices(compiled.as_text())
 
 
 def test_served_step_tp4_lowers(mesh4, on_tpu, monkeypatch):
@@ -225,6 +245,7 @@ def test_served_prefill_lowers(one_chip, on_tpu, monkeypatch):
         _served_params(one_chip), _sds((1, 512), jnp.int32, one_chip),
         None).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    assert not _weight_slices(compiled.as_text())
 
 
 # -- off-path kernels the compiler refuses today ------------------------------
