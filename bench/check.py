@@ -16,7 +16,10 @@ The sample covers what each cell's ``why`` names: the first token comes
 from the admission prefill, every later one from a decode step through
 the slot's 4096-entry ring, which has wrapped from the first decode step
 on; 8192-token prompts are in it; and in a pruned cell every projection
-runs packed.  Exact counts are compared beside it, with the limit 0:
+runs packed.  A pruned cell's masks are made again by the reference from the
+rule its file states; where the float32 rounding of the rule's threshold
+decides a block, each mask it allows is tried and the least widest gap
+counts (``run.compare``).  Exact counts are compared beside it, with the limit 0:
 finished requests that served another number of tokens than asked, and
 projections the engine served otherwise than the configuration states.
 """

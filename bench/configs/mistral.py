@@ -16,9 +16,12 @@ file: the depth cut, and ``rms_norm_eps`` (see ``rms_norm_eps`` there).
 
 Everything here is ``jax.numpy`` in float32 at ``Precision.HIGHEST``, one
 layer at a time, with no cache and no batching, and imports nothing of the
-program.  The weights are made again from the seed by ``bench.weights``
-(never taken from the program), and a pruned configuration's masks are
-made again by the rule its file states (``block_keep``).
+program.  The weights are made again from the seed by the family module
+``bench.families.mistral`` (never taken from the program), and a pruned
+configuration's masks are made again by the rule its file states
+(``block_keeps``: the rule in exact arithmetic first, then the few masks
+a threshold computed in float32 keeps instead, where it rounds onto a
+block's sum).
 
 ``precision="float8"`` is the control of the comparison that decides
 ``correct``: the same forward with both operands of every projection and
@@ -28,20 +31,22 @@ and per column of the weights), the step below the served bfloat16.
 from __future__ import annotations
 
 import functools
+import itertools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import weights as W
+from bench.families import mistral as F
 
 HI = jax.lax.Precision.HIGHEST
 CHUNK = 512          # query rows per attention block
 F8_MAX = 448.0       # largest finite float8_e4m3fn
+MAX_MASKS = 8        # masks of a seed the comparison tries at most
 
 
 def _frozen(cfg):
-    s = W.sizes(cfg)
+    s = F.sizes(cfg)
     s.update(window=cfg["sliding_window"], eps=cfg["rms_norm_eps"],
              theta=float(cfg["rope_theta"]))
     return tuple(sorted(s.items()))
@@ -49,36 +54,72 @@ def _frozen(cfg):
 
 @functools.partial(jax.jit, static_argnums=(1, 2))
 def _block_sq(w, bk, bn):
-    K, N = w.shape
+    """Each (bk, bn) block's sum of squares in float32, over all layers'
+    (layers, K, N) stack at once: the rule's sums as a float32 deployment
+    computes them, rounded alike."""
+    L, K, N = w.shape
     sq = jnp.square(w.astype(jnp.float32))
-    return sq.reshape(K // bk, bk, N // bn, bn).sum(axis=(1, 3))
+    return sq.reshape(L, K // bk, bk, N // bn, bn).sum(axis=(-3, -1))
+
+
+def rule_masks(gs, rate):
+    """The masks the rule allows over blocks' sums of squares ``gs``: a
+    block is kept when its sum exceeds the ``rate`` quantile, the linear
+    interpolation ``a (1 - w) + b w`` between the two sums ``a``, ``b`` at
+    the position ``jnp.quantile`` places in float32.  The first is the
+    rule in exact arithmetic.  The others are what the threshold keeps
+    where float32 arithmetic rounds it onto a block's sum, in any order
+    of rounding: each product rounded or fused into the sum."""
+    gs = np.asarray(gs, np.float32)
+    flat = np.sort(gs.reshape(-1))
+    pos = np.float32(rate) * np.float32(flat.size - 1)
+    low = int(np.floor(pos))
+    a, b = flat[low], flat[min(low + 1, flat.size - 1)]
+    hw = pos - np.float32(low)
+    lw = np.float32(1) - hw
+    pa, pb = np.float64(a) * np.float64(lw), np.float64(b) * np.float64(hw)
+    f32 = lambda x: np.float64(np.float32(x))  # noqa: E731
+    thresholds = [pa + pb, f32(f32(pa) + f32(pb)), f32(pa + f32(pb)),
+                  f32(f32(pa) + pb), f32(pa + pb)]
+    masks = {}
+    for t in thresholds:
+        m = gs.astype(np.float64) > t
+        masks.setdefault(int(m.sum()), m)
+    return list(masks.values())
+
+
+def block_keeps(cfg, seed):
+    """The pruned configuration's masks by the rule its file states, or
+    [None] for a dense one: a list of {projection: (layers, K/bk, N/bn)
+    bool}.  For each projection, over all layers together, a (bk, bn)
+    block is kept when its sum of squares exceeds the ``rate`` quantile
+    (linear interpolation) of all of them (``rule_masks``), the sums taken
+    in float32 (``_block_sq``).  The first is the rule in exact arithmetic
+    over those sums; then, fewest departures first, those whose
+    projections keep what a threshold computed in float32 keeps, at most
+    ``MAX_MASKS`` in all."""
+    pr = cfg.get("pruning")
+    if not pr:
+        return [None]
+    bk, bn = pr["block"]
+    layers = [F.layer(cfg, seed, l) for l in range(cfg["num_hidden_layers"])]
+    alt = [rule_masks(_block_sq(jnp.stack([ws[n] for ws in layers]), bk, bn),
+                      pr["rate"]) for n in F.PRUNED]
+    del layers
+    picks = sorted(itertools.product(*(range(len(a)) for a in alt)),
+                   key=lambda ix: (sum(i > 0 for i in ix), ix))
+    return [{n: a[i] for n, a, i in zip(F.PRUNED, alt, ix)}
+            for ix in picks[:MAX_MASKS]]
 
 
 def block_keep(cfg, seed):
-    """The pruned configuration's masks by the rule its file states, or
-    None for a dense one: {projection: (layers, K/bk, N/bn) bool}.  For
-    each projection, over all layers together, a (bk, bn) block is kept
-    when its sum of squares exceeds the ``rate`` quantile (linear
-    interpolation) of all of them."""
-    pr = cfg.get("pruning")
-    if not pr:
-        return None
-    bk, bn = pr["block"]
-    g = {n: [] for n in W.PROJ}
-    for l in range(cfg["num_hidden_layers"]):
-        ws = W.layer(cfg, seed, l)
-        for n in W.PROJ:
-            g[n].append(_block_sq(ws[n], bk, bn))
-        del ws
-    keep = {}
-    for n in W.PROJ:
-        gs = jnp.stack(g[n])
-        keep[n] = np.asarray(gs > jnp.quantile(gs.reshape(-1), pr["rate"]))
-    return keep
+    """The rule's masks in exact arithmetic (``block_keeps``' first), or
+    None for a dense configuration."""
+    return block_keeps(cfg, seed)[0]
 
 
 def _rmsnorm(x, eps):
-    """RMSNorm; every scale is 1 (``bench.weights``)."""
+    """RMSNorm; every scale is 1 (``bench.families.mistral``)."""
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
 
 
@@ -184,10 +225,10 @@ def logits(cfg, seed, tokens, first, n_out, keep=None,
     Tp = -(-T // CHUNK) * CHUNK
     ids = np.zeros((Tp,), np.int32)
     ids[:len(tokens)] = tokens
-    x = _embed(W.table(cfg, seed, "embed"), jnp.asarray(ids))
+    x = _embed(F.table(cfg, seed, "embed"), jnp.asarray(ids))
     for l in range(cfg["num_hidden_layers"]):
         kl = None if keep is None else {n: jnp.asarray(keep[n][l])
-                                        for n in W.PROJ}
-        x = _layer_fwd(x, W.layer(cfg, seed, l), kl, frozen, precision)
-    return np.asarray(_head(x, W.table(cfg, seed, "head"), first, n_out,
+                                        for n in F.PRUNED}
+        x = _layer_fwd(x, F.layer(cfg, seed, l), kl, frozen, precision)
+    return np.asarray(_head(x, F.table(cfg, seed, "head"), first, n_out,
                             frozen, precision))

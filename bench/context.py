@@ -1,5 +1,6 @@
 """What a per-layer metric's reader gets: the reduced trace, the host
-record, and the work counts, with the lookups several readers share.
+record, and the cell's family module (``model``), which counts the work,
+with the lookups several readers share.
 
 A reader is ``bench/metrics/<metric>.py`` with ``read(ctx) -> float |
 None``; None means it found nothing to read, and the metric is left out
@@ -7,6 +8,7 @@ of the result line.
 """
 from __future__ import annotations
 
+import bisect
 import dataclasses
 
 from bench import stats, work
@@ -15,6 +17,7 @@ from bench import stats, work
 @dataclasses.dataclass
 class Context:
     cfg: dict                 # the configuration file
+    model: object             # its family module (bench/families)
     keep: dict | None         # live blocks (reference masks), or None
     peaks: dict               # the device's peaks (bench/peaks.json)
     n_slots: int
@@ -31,6 +34,23 @@ class Context:
         if st is None or not 0 <= st.index < len(self.record.steps):
             return None
         return self.record.steps[st.index]
+
+    def slot_lengths(self, host_step):
+        """The cache length of each slot the engine stepped in
+        ``host_step``, the step's own entry included: the request's prompt,
+        the tokens it received before the step, and the one the step feeds
+        back.  A stepped request received one token when the step returned
+        (two if the step admitted it, the prefill's first)."""
+        t_end = host_step[1]
+        out = []
+        for e in self.record.entries:
+            n = bisect.bisect_right(e.times, t_end)
+            fed = n - bisect.bisect_left(e.times, t_end)
+            if e.times and e.times[0] == t_end:
+                fed -= 1                    # the prefill's token, not fed
+            if fed > 0:
+                out.append(e.due.prompt_len + n - 1)
+        return out
 
     def admitted_in(self, host_step):
         """Prompt lengths of the requests admitted in ``host_step``, in

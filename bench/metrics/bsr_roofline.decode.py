@@ -1,8 +1,7 @@
 """``bsr_matmul_packed``'s share of its roofline in the serving step, %:
 the least time of the packed projections' required work (live blocks at
-M = the engine's slots, ``work.packed_projections``) over the device time
-of the Pallas kernel launches inside the step programs."""
-from bench import work
+M = the engine's slots, the family's ``packed_projections``) over the
+device time of the Pallas kernel launches inside the step programs."""
 
 
 def read(ctx):
@@ -13,5 +12,5 @@ def read(ctx):
     if kt <= 0:
         return None
     least = len(steps) * ctx.least(
-        work.packed_projections(ctx.cfg, ctx.keep, ctx.n_slots))
+        ctx.model.packed_projections(ctx.cfg, ctx.keep, ctx.n_slots))
     return 100.0 * least / kt

@@ -2,7 +2,6 @@
 %: the least time of the packed projections' required work at M = the
 prompt length, over the device time of the Pallas kernel launches inside
 the prefill programs."""
-from bench import work
 
 
 def read(ctx):
@@ -15,6 +14,6 @@ def read(ctx):
              for o in ctx.red.ops_in(p, "kernel"))
     if kt <= 0:
         return None
-    least = sum(ctx.least(work.packed_projections(ctx.cfg, ctx.keep, P))
+    least = sum(ctx.least(ctx.model.packed_projections(ctx.cfg, ctx.keep, P))
                 for _, P in matched)
     return 100.0 * least / kt

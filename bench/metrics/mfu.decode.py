@@ -1,9 +1,9 @@
 """The decode cycle's share of the chip's peak, %: the least time the
-required work of each serving step takes at the peaks (``work.decode_step``
-for its active slots), over the wall interval from its start to the next
-step's start.  Taken over the cycles with no prefill in them, so it reads
-the decode path as it runs between admissions."""
-from bench import work
+required work of each serving step takes at the peaks (the family's
+``decode_step`` over its active slots' cache lengths), over the wall
+interval from its start to the next step's start.  Taken over the cycles
+with no prefill in them, so it reads the decode path as it runs between
+admissions."""
 
 
 def read(ctx):
@@ -14,8 +14,9 @@ def read(ctx):
         if any(a.start <= p.start < b.start for p in pre):
             continue
         hs = ctx.host_step(a)
-        if hs is None or hs[2] == 0:
+        lengths = None if hs is None else ctx.slot_lengths(hs)
+        if not lengths:
             continue
-        least += ctx.least(work.decode_step(ctx.cfg, hs[2], ctx.keep))
+        least += ctx.least(ctx.model.decode_step(ctx.cfg, lengths, ctx.keep))
         wall += b.start - a.start
     return 100.0 * least / wall if wall > 0 else None

@@ -142,14 +142,13 @@ def compare(cell, seed, record, counts, controls=()):
     freed: (checks, correct, the reference's masks, {control: (checks,
     correct)}).  A control puts in the served tokens' place the tokens
     the reference computed at that precision ranks first, and is judged
-    by the same limits."""
+    by the same limits.  Every one of the reference's masks
+    (``block_keeps``) is tried; each number is the least over them, and
+    the masks returned are those that explain the served tokens best."""
     import numpy as np
     from bench import check
     ref = cell.reference()
-    keep = ref.block_keep(cell.config, seed)
     cases = check.cases(record.entries, seed, cell.config["vocab_size"])
-    rows = check.served_gaps(ref, cell.config, seed, cases,
-                             cell.traffic["output"]["max"], keep, controls)
     values = dict(counts)
     values["short_outputs"] = sum(
         len(e.tokens) != e.due.max_new_tokens for e in record.entries
@@ -157,18 +156,27 @@ def compare(cell, seed, record, counts, controls=()):
     log(f"check: {len(cases)} requests, "
         f"{sum(len(c[1]) for c in cases)} served tokens compared "
         f"(prompts {[len(c[0]) for c in cases]})")
-
-    for key in ("served",) + tuple(controls):
-        g = np.concatenate([r[key] for r in rows]) if rows else np.zeros(0)
-        log(f"check {key}: gaps over {g.size} tokens: mean {g.mean():.4g}, "
-            f"share above 0 {np.mean(g > 0):.4g}, widest "
-            f"{g.max():.4g}" if g.size else f"check {key}: no tokens")
+    keeps = ref.block_keeps(cell.config, seed)
+    widest = {key: [] for key in ("served",) + tuple(controls)}
+    for i, keep in enumerate(keeps):
+        rows = check.served_gaps(ref, cell.config, seed, cases,
+                                 cell.traffic["output"]["max"], keep, controls)
+        for key in widest:
+            g = (np.concatenate([r[key] for r in rows]) if rows
+                 else np.zeros(0))
+            log(f"check {key}, masks {i + 1} of {len(keeps)}: gaps over "
+                f"{g.size} tokens: mean {g.mean():.4g}, share above 0 "
+                f"{np.mean(g > 0):.4g}, widest {g.max():.4g}" if g.size
+                else f"check {key}: no tokens")
+            widest[key].append(float(g.max()) if g.size else np.inf)
 
     def judged(key):
-        gap = max(float(r[key].max()) for r in rows) if rows else None
-        return check.verdict(dict(values, served_gap_max=gap),
-                             cell.params["limits"])
+        gap = min(widest[key])
+        return check.verdict(
+            dict(values, served_gap_max=gap if np.isfinite(gap) else None),
+            cell.params["limits"])
     checks, ok = judged("served")
+    keep = keeps[int(np.argmin(widest["served"]))]
     return checks, ok, keep, {c: judged(c) for c in controls}
 
 
@@ -224,7 +232,7 @@ def run(cell, seed, seconds, trace, devs):
         red = tr.reduce(tr.load(TRACE_DIR))
         tr.remove(TRACE_DIR)
         ctx = context.Context(
-            cfg=cell.config, keep=keep, peaks=peaks,
+            cfg=cell.config, model=cell.family(), keep=keep, peaks=peaks,
             n_slots=cell.traffic["n_slots"], red=red, record=record,
             window=window, compiles=[t - record.t0 for t in compiles])
         result["metrics"] = per_layer(cell, ctx)

@@ -3,12 +3,18 @@
     BENCHMARK.json                  the cells, metrics and bounds
     bench/configs/<config>.json     a configuration's sizes (``file``)
     bench/configs/<reference>.py    its plain reference, named in the file
+    bench/families/<family>.py      its family module, named in the file
     bench/traffic/<traffic>.json    a traffic mix's parameters
     bench/workloads/<cell>.json     a cell's rate and correctness limits
     bench/metrics/<metric>.py       a per-layer metric's reader
     bench/peaks.json                the chips' peaks, by device kind
 
-A later cell, configuration, mix or metric is added by adding files.
+A later cell, configuration, mix or metric is added by adding files.  A
+configuration brings its ``.json``, its plain reference (which imports
+nothing of the program) and, for an architecture the harness does not
+know yet, its family module (``bench/families/__init__.py`` lists what
+one provides).  The generic harness reaches the model only through the
+family module.
 """
 from __future__ import annotations
 
@@ -49,6 +55,14 @@ class Cell:
         """The configuration's plain reference module."""
         return importlib.import_module(
             f"bench.configs.{self.config['reference']}")
+
+    def family(self):
+        """The configuration's family module."""
+        if "family" not in self.config:
+            raise SpecError(f"configuration {self.config_name!r} names no "
+                            f"family")
+        return importlib.import_module(
+            f"bench.families.{self.config['family']}")
 
 
 def _applies(metric, cell):

@@ -1,33 +1,19 @@
 """The system under test, built through the program's own entry points.
 
-Weights come from ``bench.weights`` (made from the seed on the device).  A
-configuration with ``pruning`` goes through ``launch.serve.prune`` and
-``serve.compile.compile_model(CompileSpec(keep_dense=False))``, so every
-projection is served packed; a dense one is served as it is.  Either is
-handed to ``serve.engine.ServingEngine`` with the mix's slots and ring.
+The cell's family module (``bench/families/<family>.py``) gives the
+program's ``ArchConfig`` and the weights, made from the seed on the
+device.  A configuration with ``pruning`` goes through
+``launch.serve.prune`` and ``serve.compile.compile_model(CompileSpec(
+keep_dense=False))``, so every projection the family names is served
+packed; a dense one is served as it is.  Either is handed to
+``serve.engine.ServingEngine`` with the mix's slots and ring.
 """
 from __future__ import annotations
 
-import re
 import time
 
 import jax
 import numpy as np
-
-from bench import weights as W
-
-PROJ_PATH = re.compile(r"(attn/w[qkvo]|ffn/(gate|up|down))/w$")
-
-
-def arch_config(cfg):
-    """The program's ``ArchConfig`` for a configuration file."""
-    from repro.configs.base import ArchConfig
-    s = W.sizes(cfg)
-    return ArchConfig(
-        name=cfg["model_type"], family="dense", n_layers=s["L"],
-        d_model=s["d"], n_heads=s["H"], n_kv_heads=s["KV"], d_ff=s["ff"],
-        vocab=s["V"], head_dim=s["hd"], sliding_window=cfg["sliding_window"],
-        rope_theta=float(cfg["rope_theta"]))
 
 
 def _timed(times, name, fn, *args, **kw):
@@ -45,8 +31,9 @@ def build(cell, seed, times):
     from repro.serve.engine import ServingEngine
 
     cfg, mix = cell.config, cell.traffic
-    acfg = arch_config(cfg)
-    params = _timed(times, "init", W.init, cfg, seed)
+    model = cell.family()
+    acfg = model.arch_config(cfg)
+    params = _timed(times, "init", model.init, cfg, seed)
     unpacked = 0
     if cfg.get("pruning"):
         masked, masks, spec = _timed(times, "mask", prune, params, acfg,
@@ -57,8 +44,8 @@ def build(cell, seed, times):
                                        spec=CompileSpec(keep_dense=False))
         times["pack"] = time.perf_counter() - t0
         del masked, masks
-        unpacked = len(W.PROJ) - sum(bool(PROJ_PATH.search(r.path))
-                                     for r in report.packed)
+        unpacked = len(model.PRUNED) - sum(model.is_pruned(r.path)
+                                           for r in report.packed)
     t0 = time.perf_counter()
     eng = ServingEngine(params, acfg, n_slots=mix["n_slots"],
                         seq_cap=mix["seq_cap"])

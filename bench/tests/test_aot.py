@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from bench import spec, system, weights as W
+from bench import spec
 
 CELLS = [w["name"] for w in json.loads(
     (spec.ROOT / "BENCHMARK.json").read_text())["workloads"]]
@@ -75,17 +75,32 @@ def _packed(K, N, block, density, lead, sharding):
         scales=None, block=tuple(block), shape=(K, N), n_shards=0)
 
 
-def _served_params(cfg, sharding):
-    """Shapes of what the cell serves: the bench's weights, with every
-    projection packed where the configuration prunes."""
-    p = _placed(jax.eval_shape(lambda: W.init(cfg, 0)), sharding)
+def _pack_pruned(tree, model, block, density, sharding, path=""):
+    """``tree`` with every node whose weight ``model.is_pruned`` names
+    replaced by its packed layout, as ``compile_model(keep_dense=False)``
+    serves it (the leading stack dims kept)."""
+    out = {}
+    for k, v in tree.items():
+        at = f"{path}/{k}" if path else k
+        if isinstance(v, dict) and "w" in v and model.is_pruned(f"{at}/w"):
+            w = v["w"]
+            out[k] = {"packed": _packed(*w.shape[-2:], block, density,
+                                        w.shape[:-2], sharding)}
+        elif isinstance(v, dict):
+            out[k] = _pack_pruned(v, model, block, density, sharding, at)
+        else:
+            out[k] = v
+    return out
+
+
+def _served_params(cell, sharding):
+    """Shapes of what the cell serves: the family's weights, with every
+    projection it prunes packed where the configuration prunes."""
+    model, cfg = cell.family(), cell.config
+    p = _placed(jax.eval_shape(lambda: model.init(cfg, 0)), sharding)
     pr = cfg.get("pruning")
     if pr:
-        L = cfg["num_hidden_layers"]
-        for name, (K, N) in W.proj_shapes(W.sizes(cfg)).items():
-            group, leaf = name.split("/")
-            p["layers"][group][leaf] = {"packed": _packed(
-                K, N, pr["block"], 1 - pr["rate"], (L,), sharding)}
+        p = _pack_pruned(p, model, pr["block"], 1 - pr["rate"], sharding)
     return p
 
 
@@ -106,8 +121,8 @@ def test_cell_programs_compile(cell_name, one_chip, on_tpu):
     from repro.serve import kvcache as KV
     cell = spec.load(cell_name)
     cfg, mix = cell.config, cell.traffic
-    acfg = system.arch_config(cfg)
-    params = _served_params(cfg, one_chip)
+    acfg = cell.family().arch_config(cfg)
+    params = _served_params(cell, one_chip)
     for P, _ in mix["prompt_lengths"]:
         c = E._jit_prefill(acfg, None).lower(
             params, _sds((1, P), jnp.int32, one_chip), None).compile()

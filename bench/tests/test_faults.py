@@ -92,6 +92,30 @@ def test_float8_control_is_not_correct(cell):
     assert f8_checks["served_gap_max"]["value"] > LIMIT
 
 
+def test_compare_tries_every_rule_mask(cell, monkeypatch):
+    """Where the first of the reference's masks does not explain the
+    served tokens (here it keeps every block the program pruned), the
+    others are tried: the run is judged by the one that does, and the
+    masks returned are those.  With only the wrong masks it is not
+    correct."""
+    from bench.configs import mistral
+    seed = 21
+    eng, counts = system.build(cell, seed, {})
+    record, _, _ = R.serve(cell, eng, seed, 4.0, 0)
+    del eng
+    right = mistral.block_keeps(cell.config, seed)[0]
+    wrong = {n: np.ones_like(m) for n, m in right.items()}
+    monkeypatch.setattr(mistral, "block_keeps",
+                        lambda cfg, s: [wrong, right, wrong])
+    checks, ok, keep, _ = R.compare(cell, seed, record, counts)
+    assert ok, checks
+    assert keep is right
+    monkeypatch.setattr(mistral, "block_keeps", lambda cfg, s: [wrong])
+    checks, ok, keep, _ = R.compare(cell, seed, record, counts)
+    assert not ok and checks["served_gap_max"]["value"] > LIMIT
+    assert keep is wrong
+
+
 def test_verdict_reads_every_limit():
     checks, ok = check.verdict({"a": 0.1, "b": 0}, {"a": 0.2, "b": 0})
     assert ok and checks == {"a": {"value": 0.1, "limit": 0.2},

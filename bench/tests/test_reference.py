@@ -8,8 +8,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import system, weights as W
 from bench.configs import mistral
+from bench.families import mistral as F
 
 CFG = {"model_type": "mistral", "hidden_size": 128,
        "num_attention_heads": 4, "num_key_value_heads": 2,
@@ -27,10 +27,10 @@ def _program_logits(cfg, prompt, forced):
     from repro.models import transformer as T
     from repro.serve import engine as E
     from repro.serve import kvcache as KV
-    acfg = system.arch_config(cfg)
+    acfg = F.arch_config(cfg)
     # the served bfloat16 weights, computed in float32 as the reference is
     params = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32),
-                                    W.init(cfg, SEED))
+                                    F.init(cfg, SEED))
     logits, rc = E.prefill(params, acfg, jnp.asarray([prompt], jnp.int32))
     out = [np.asarray(logits[0, -1])]
     cache = KV.init_slots(params, acfg, 2, cfg["sliding_window"],
@@ -84,8 +84,45 @@ def test_block_keep_follows_the_program_masks():
     cfg = dict(CFG, hidden_size=256, intermediate_size=512,
                pruning={"block": [128, 128], "rate": 0.6})
     keep = mistral.block_keep(cfg, SEED)
-    _, masks, _ = prune(W.init(cfg, SEED), system.arch_config(cfg), 0.6)
-    for name in W.PROJ:
+    _, masks, _ = prune(F.init(cfg, SEED), F.arch_config(cfg), 0.6)
+    for name in F.PRUNED:
         group, leaf = name.split("/")
         m = np.asarray(masks["layers"][group][leaf]["w"])[:, ::128, ::128]
         np.testing.assert_array_equal(m.astype(bool), keep[name])
+
+
+def test_rule_masks_admit_the_block_a_rounded_threshold_lands_on():
+    """At rate 0.78 over 11 blocks the interpolated quantile of two sums
+    one float apart rounds onto the upper one, so a float32 threshold
+    drops a block the rule in exact arithmetic keeps: the exact mask
+    (3 blocks) comes first, the rounded one (2) is admitted beside it."""
+    a = np.float32(6.573404312133789)
+    b = np.nextafter(a, np.float32(np.inf))
+    gs = np.array([1, 2, 3, 4, 5, 6, 5.5, a, b, 7, 8], np.float32)
+    rounded = gs > np.asarray(jnp.quantile(jnp.asarray(gs), 0.78))
+    assert int(rounded.sum()) == 2
+    masks = mistral.rule_masks(gs, 0.78)
+    np.testing.assert_array_equal(masks[0], gs >= b)
+    assert any(np.array_equal(m, rounded) for m in masks[1:])
+    assert {int(m.sum()) for m in masks} <= {1, 2, 3, 4}
+
+
+def test_rule_masks_are_one_where_no_sum_lies_near_the_threshold():
+    """Sums a unit apart, a threshold between two of them: only the exact
+    rule's mask, the same as a float32 threshold keeps."""
+    distinct = np.random.default_rng(3).permutation(101).astype(np.float32)
+    for rate in (0.255, 0.505, 0.6049, 0.783):
+        masks = mistral.rule_masks(distinct.reshape(1, 101), rate)
+        assert len(masks) == 1
+        np.testing.assert_array_equal(
+            masks[0], distinct.reshape(1, 101) > jnp.quantile(distinct, rate))
+
+
+def test_block_keeps_put_the_exact_rule_first():
+    cfg = dict(CFG, hidden_size=256, intermediate_size=512,
+               pruning={"block": [128, 128], "rate": 0.6})
+    keeps = mistral.block_keeps(cfg, SEED)
+    assert 1 <= len(keeps) <= mistral.MAX_MASKS
+    for name, m in mistral.block_keep(cfg, SEED).items():
+        np.testing.assert_array_equal(keeps[0][name], m)
+    assert mistral.block_keeps(CFG, SEED) == [None]

@@ -27,6 +27,7 @@ def test_contract_keys():
 def test_cell_finds_its_files(name):
     cell = spec.load(name)
     assert cell.reference().logits
+    assert cell.family().arch_config(cell.config)
     assert {"rate_per_s", "limits"} <= set(cell.params)
     assert cell.per_layer and cell.end_to_end
     for m in cell.per_layer:
@@ -36,8 +37,9 @@ def test_cell_finds_its_files(name):
 @pytest.mark.parametrize("metric", [m["name"] for m in BENCH["per_layer"]])
 def test_reader_finds_nothing_in_an_empty_trace(metric):
     red = trace.Reduced((0.0, 1.0), [], [], [])
+    cell = spec.load(CELLS[0])
     ctx = context.Context(
-        cfg=spec.load(CELLS[0]).config, keep=None,
+        cfg=cell.config, model=cell.family(), keep=None,
         peaks={"bf16_flops_per_s": 1.0, "hbm_bytes_per_s": 1.0}, n_slots=16,
         red=red, record=Record([], [], 0.0, 0.0), window=(0.0, 1.0),
         compiles=[])

@@ -6,10 +6,11 @@ from pathlib import Path
 
 from bench import spec
 
-CONFIG = {"reference": "mistral", "model_type": "mistral",
-          "hidden_size": 256, "num_attention_heads": 2,
-          "num_key_value_heads": 2, "intermediate_size": 512,
-          "vocab_size": 512, "num_hidden_layers": 2, "sliding_window": 64,
+CONFIG = {"reference": "mistral", "family": "mistral",
+          "model_type": "mistral", "hidden_size": 256,
+          "num_attention_heads": 2, "num_key_value_heads": 2,
+          "intermediate_size": 512, "vocab_size": 512,
+          "num_hidden_layers": 2, "sliding_window": 64,
           "rope_theta": 10000.0, "rms_norm_eps": 1e-6,
           "initializer_range": 0.02}
 PRUNING = {"block": [128, 128], "rate": 0.6}
